@@ -39,7 +39,8 @@ _DTYPE_BYTES = {
 
 _SHAPE_RE = re.compile(r"\b([a-z]+[0-9]+(?:[a-z0-9]*)?|pred)\[([\d,]*)\]")
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s*(.+?)\s([a-z][a-z0-9\-]*)\((.*)$")
+    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.+?)\s([a-z][a-z0-9\-]*)\((.*)$")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\)\s*->.*{\s*$")
 _CALLED_RE = re.compile(r"(?:calls|to_apply|body)=%([\w.\-]+)")
 _COND_BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
@@ -131,27 +132,39 @@ class HLOAnalyzer:
             return self._cost_cache[name]
         # memoize a zero first: malformed self-recursive graphs terminate
         self._cost_cache[name] = Cost()
+        lines = self.computations.get(name, ())
+        # instruction name -> result type: operands are printed by name
+        # only (``dot(%x.1, %y.1)``), so their shapes come from the
+        # instruction that defines them
+        types = {m.group(1): m.group(2)
+                 for m in map(_INSTR_RE.match, lines) if m}
         total = Cost()
-        for line in self.computations.get(name, ()):
-            total.add(self._instruction_cost(line))
+        for line in lines:
+            total.add(self._instruction_cost(line, types))
         self._cost_cache[name] = total
         return total
 
-    def _instruction_cost(self, line: str) -> Cost:
+    @staticmethod
+    def _operand_types(rest: str, types: dict[str, str]) -> list[str]:
+        """Types of an instruction's operands, in order: each operand of
+        ``rest`` (the text after the opcode's parenthesis) is resolved by
+        name through the computation's definitions."""
+        args = rest.split(")", 1)[0]
+        return [types[n] for n in _OPERAND_RE.findall(args) if n in types]
+
+    def _instruction_cost(self, line: str, types: dict[str, str]) -> Cost:
         m = _INSTR_RE.match(line)
         if not m:
             return Cost()
-        result_type, opcode, rest = m.groups()
+        _, result_type, opcode, rest = m.groups()
         c = Cost()
         if opcode == "dot":
-            self._dot_cost(result_type, rest, c, line)
-        elif opcode == "convolution":
-            # window sizes are not recovered here; count traffic only
-            c.bytes += _shapes_bytes(result_type) + _shapes_bytes(
-                rest.split("),")[0])
-        elif opcode == "custom-call":
-            c.bytes += _shapes_bytes(result_type) + _shapes_bytes(
-                rest.split("),")[0])
+            self._dot_cost(result_type, self._operand_types(rest, types), c,
+                           line)
+        elif opcode in ("convolution", "custom-call"):
+            # convolution window sizes are not recovered; traffic only
+            c.bytes += _shapes_bytes(result_type) + sum(
+                _shapes_bytes(t) for t in self._operand_types(rest, types))
             for sub in _CALLED_RE.findall(line):
                 c.add(self.computation_cost(sub))
         elif opcode in ("fusion", "call"):
@@ -174,10 +187,11 @@ class HLOAnalyzer:
             c.colls.append((b, f"{opcode} {result_type.strip()}"))
         return c
 
-    def _dot_cost(self, result_type: str, rest: str, c: Cost,
+    def _dot_cost(self, result_type: str, operand_types: list[str], c: Cost,
                   line: str) -> None:
         out_shape = _SHAPE_RE.search(result_type)
-        operands = _SHAPE_RE.findall(rest)
+        operands = [m.groups() for m in map(_SHAPE_RE.search, operand_types)
+                    if m]
         if not out_shape or not operands:
             return
         out_dims = _shape_dims(out_shape.groups())

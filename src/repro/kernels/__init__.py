@@ -4,21 +4,22 @@
 """Shared kernel-package plumbing.
 
 Every Pallas kernel in this tree takes an ``interpret`` flag. Its
-*default* is derived here, in one place, from the runtime platform:
-interpret mode (kernel body executed by the Pallas interpreter — correct
-everywhere, fast nowhere) on CPU hosts, the compiled Mosaic path on
-accelerators. Callers that need to force a mode (tests pinning interpret
-semantics, TPU debugging) still pass an explicit bool; passing ``None``
-(the default everywhere) means "whatever this platform wants".
+*default* is derived here, in one place, from the runtime platform: the
+compiled Mosaic path on a TPU, interpret mode (kernel body executed by
+the Pallas interpreter — correct everywhere, fast nowhere) on anything
+else. The kernels are written for the TPU only, so no other accelerator
+gets the compiled path. Callers that need to force a mode (tests pinning
+interpret semantics, the chip path insisting on compiled kernels) pass an
+explicit bool; passing ``None`` means "whatever this platform wants".
 """
 from __future__ import annotations
 
 
 def default_interpret() -> bool:
-    """True iff Pallas kernels should run in interpret mode here: CPU
-    hosts interpret; TPU/GPU run the compiled kernel path."""
+    """True iff Pallas kernels should run in interpret mode here: a TPU
+    runs the compiled kernel path, every other backend interprets."""
     import jax
-    return jax.default_backend() not in ("tpu", "gpu")
+    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret) -> bool:

@@ -60,8 +60,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t2 = time.time()
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):         # older jax returns [dict]
-        ca = ca[0]
     rec = {
         "arch": arch,
         "shape": shape_name,

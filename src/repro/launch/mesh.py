@@ -10,11 +10,8 @@ import jax
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # axis_types arrived after jax 0.4.37; Auto is the default either way
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -4,9 +4,21 @@
         --policy continuum --workload swe-bench -n 60 --rate 0.05 \
         [--offload-gb 200] [--trace trace.json] [--engines 2]
 
-Uses the virtual-clock simulation backend (cost-model timed; the scheduler
-code is the production code). For real token generation on CPU see
-examples/quickstart.py.
+``--backend sim`` (the default) runs the virtual-clock simulation backend
+(cost-model timed; the scheduler code is the production code).
+``--backend jax`` serves for real on one TPU chip — Engine ->
+JaxModelBackend -> PagedKVRuntime -> the compiled Pallas kernels — with
+seeded random weights at the arch's full published width::
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
+        --backend jax --workload bfcl -n 4 --max-len 8192 --offload-gb 8
+
+It refuses a host without a TPU; ``--smoke`` swaps in the arch's smoke
+config and lets the CPU run it with interpreted kernels (a rehearsal,
+not a measurement; give it ``--kv-budget-gb``, as the CPU reports no
+memory limit). The compile cache lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``.jax_cache/`` at the root
+of the checkout.
 
 Observability front door::
 
@@ -27,11 +39,15 @@ behind a session router.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
+import os
+import pathlib
 import sys
 import time
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core.policies import POLICIES
 from repro.serving.engine import Engine, EngineConfig
 from repro.serving.offload import OffloadConfig
@@ -41,6 +57,54 @@ from repro.sim.runner import run_workload
 from repro.sim.workload import WORKLOADS, generate_programs, load_trace
 
 CLUSTER_ROUTERS = ("round_robin", "sticky", "kv_aware", "kv_aware_migrate")
+
+#: fixed compile-cache directory of this checkout (git-ignored); the
+#: cache key includes the path, so it must not move between runs
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache before anything compiles:
+    ``JAX_COMPILATION_CACHE_DIR`` wins where it is set (JAX reads it
+    itself), else :data:`COMPILE_CACHE_DIR`. Every compile is kept, not
+    only those over a second: a served run compiles hundreds of small
+    programs (one per bucketed shape). Returns the directory."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
+def build_jax_engine(cfg: ModelConfig, ecfg: EngineConfig, *, max_len: int,
+                     seed: int = 0, allow_cpu: bool = False) -> Engine:
+    """The served path on one device: an Engine over a JaxModelBackend
+    (seeded random weights, ``max_len``-token programs) over a
+    PagedKVRuntime. On a TPU the kernels run compiled and the hardware
+    profile is the device's published peaks; anything else is refused
+    unless ``allow_cpu`` (the interpreted rehearsal on a smoke config)."""
+    import jax
+    from repro.serving.backend import JaxModelBackend
+    from repro.serving.profiler import hardware_profile
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        hw, interpret = hardware_profile(dev.device_kind), False
+    elif allow_cpu:
+        if not ecfg.kv_budget_bytes:
+            raise ValueError("the CPU rehearsal needs an explicit KV budget "
+                             "(the CPU reports no memory limit)")
+        hw, interpret = HardwareProfile(), True
+    else:
+        raise RuntimeError(
+            f"the jax backend serves on a TPU, but JAX found "
+            f"{dev.platform!r} ({dev.device_kind}); use --backend sim, or "
+            f"--smoke for the CPU rehearsal")
+    backend = JaxModelBackend(cfg, rng=jax.random.PRNGKey(seed),
+                              max_len=max_len, page_size=ecfg.block_size,
+                              interpret=interpret)
+    return Engine(cfg, ecfg, hw, backend=backend)
 
 
 def main() -> int:
@@ -67,9 +131,21 @@ def main() -> int:
                     help="host-DRAM tier capacity (0 = offload disabled)")
     ap.add_argument("--ssd-gb", type=float, default=0.0,
                     help="SSD spillover tier below DRAM (needs --offload-gb)")
-    ap.add_argument("--kv-budget-gb", type=float, default=40.0)
+    ap.add_argument("--kv-budget-gb", type=float, default=None,
+                    help="KV pool size (default: 40 for --backend sim; "
+                         "what fits on the chip for --backend jax)")
     ap.add_argument("--max-batch", type=int, default=48)
     ap.add_argument("--chunk-size", type=int, default=2048)
+    ap.add_argument("--backend", default="sim", choices=("sim", "jax"),
+                    help="sim: virtual clock; jax: real generation on "
+                         "one TPU chip (single engine only)")
+    ap.add_argument("--max-len", type=int, default=8192,
+                    help="--backend jax: longest program (tokens, all "
+                         "turns) the backend holds; longer ones are "
+                         "refused")
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the arch's smoke config; with --backend "
+                         "jax this allows the CPU (interpreted kernels)")
     ap.add_argument("--cost-source", default="analytic",
                     choices=("analytic", "roofline"),
                     help="roofline: calibrate the TTL cost model from the "
@@ -104,12 +180,25 @@ def main() -> int:
 
     if args.router is None:
         args.router = "kv_aware_migrate" if args.cluster else "session"
-    cfg = get_config(args.arch)
+    if args.backend == "jax" and (args.engines > 1 or args.cluster):
+        ap.error("--backend jax serves one engine in one process (a chip "
+                 "belongs to one engine); drop --engines/--cluster")
+    cfg = get_config(args.arch, smoke=args.smoke)
     if args.trace:
         programs = load_trace(args.trace)
     else:
-        programs = generate_programs(WORKLOADS[args.workload], n=args.n,
-                                     rate_jps=args.rate, seed=args.seed)
+        spec = WORKLOADS[args.workload]
+        if args.backend == "jax":
+            spec = dataclasses.replace(spec, max_context=args.max_len)
+        programs = generate_programs(spec, n=args.n, rate_jps=args.rate,
+                                     seed=args.seed)
+    if args.backend == "jax":
+        too_long = [p.program_id for p in programs
+                    if p.total_tokens() > args.max_len]
+        if too_long:
+            ap.error(f"{len(too_long)} programs exceed --max-len "
+                     f"{args.max_len} tokens (e.g. {too_long[0]}); raise "
+                     f"--max-len or shorten the workload")
     if args.cluster and args.router == "kv_aware_migrate" \
             and not args.offload_gb:
         # migration stages KV through the host tier on both ends
@@ -126,11 +215,22 @@ def main() -> int:
         from repro.serving.profiler import CostModel
         cost = CostModel.from_roofline(cfg, chips=args.chips)
     id_prefix = "r" if args.cluster else "e"
-    engines = [Engine(cfg, EngineConfig(
-        policy=args.policy, chips=args.chips, offload=off,
-        max_batch=args.max_batch, chunk_size=args.chunk_size,
-        kv_budget_bytes=args.kv_budget_gb * 1e9), HardwareProfile(),
-        cost=cost, engine_id=f"{id_prefix}{i}") for i in range(args.engines)]
+    if args.backend == "jax":
+        enable_compile_cache()
+        # one chip: chips=1, and the KV budget is what fits on it
+        engines = [build_jax_engine(cfg, EngineConfig(
+            policy=args.policy, chips=1, offload=off,
+            max_batch=args.max_batch, chunk_size=args.chunk_size,
+            kv_budget_bytes=(args.kv_budget_gb or 0.0) * 1e9),
+            max_len=args.max_len, seed=args.seed, allow_cpu=args.smoke)]
+    else:
+        engines = [Engine(cfg, EngineConfig(
+            policy=args.policy, chips=args.chips, offload=off,
+            max_batch=args.max_batch, chunk_size=args.chunk_size,
+            kv_budget_bytes=(args.kv_budget_gb or 40.0) * 1e9),
+            HardwareProfile(),
+            cost=cost, engine_id=f"{id_prefix}{i}")
+            for i in range(args.engines)]
 
     cluster = None
     if args.cluster:
@@ -216,6 +316,17 @@ def main() -> int:
             "bytes_moved": {c: round(v["bytes_moved"] / 1e9, 2)
                             for c, v in ks.transfer.usage().items()},
         }
+    if args.backend == "jax":
+        import jax
+        be = engines[0].backend
+        dev = jax.devices()[0]
+        out["device"] = {"platform": dev.platform, "kind": dev.device_kind}
+        out["backend"] = {
+            "prefill_tokens": be.prefill_tokens_computed,
+            "decode_tokens": be.decode_tokens_computed,
+            "demotions": be.demotions, "restores": be.restores,
+            "cow_splits": be.runtime.cow_splits,
+            "shortfall_tokens": be.shortfall_tokens}
     if tel is not None and tel.slo is not None:
         slo = tel.slo.status()
         out["slo"] = {"alerting": [t for t in slo["tenants"]

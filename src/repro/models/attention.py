@@ -34,11 +34,17 @@ NEG_INF = -2.0e38  # fp32 mask value (safe under bf16->fp32 upcast)
 # ---------------------------------------------------------------------------
 def attention_specs(cfg: ModelConfig, dtype: str) -> dict:
     D, H, KV, Dh = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    # head-split weights contract over D (q/k/v) or over H*Dh (o): their
+    # init fan-in is that, not the second-to-last dim
     specs = {
-        "wq": ParamSpec((D, H, Dh), ("embed", "q_heads", "head_dim"), dtype=dtype),
-        "wk": ParamSpec((D, KV, Dh), ("embed", "kv_heads", "head_dim"), dtype=dtype),
-        "wv": ParamSpec((D, KV, Dh), ("embed", "kv_heads", "head_dim"), dtype=dtype),
-        "wo": ParamSpec((H, Dh, D), ("q_heads", "head_dim", "embed"), dtype=dtype),
+        "wq": ParamSpec((D, H, Dh), ("embed", "q_heads", "head_dim"),
+                        dtype=dtype, fan_in=D),
+        "wk": ParamSpec((D, KV, Dh), ("embed", "kv_heads", "head_dim"),
+                        dtype=dtype, fan_in=D),
+        "wv": ParamSpec((D, KV, Dh), ("embed", "kv_heads", "head_dim"),
+                        dtype=dtype, fan_in=D),
+        "wo": ParamSpec((H, Dh, D), ("q_heads", "head_dim", "embed"),
+                        dtype=dtype, fan_in=H * Dh),
     }
     if cfg.qkv_bias:
         specs["bq"] = ParamSpec((H, Dh), ("q_heads", "head_dim"), init="zeros", dtype=dtype)

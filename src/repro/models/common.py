@@ -23,6 +23,7 @@ class ParamSpec:
     scale: float = 1.0
     dtype: str = "float32"
     keep_dtype: bool = False                # numerics-sensitive: never downcast
+    fan_in: int = 0                         # 0 -> shape[-2]
 
     def __post_init__(self):
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
@@ -42,7 +43,8 @@ def init_param(spec: ParamSpec, key: jax.Array) -> jax.Array:
         return jnp.zeros(spec.shape, dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, dtype)
-    fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+    fan_in = spec.fan_in or (spec.shape[-2] if len(spec.shape) >= 2
+                             else spec.shape[-1])
     if spec.init == "embed":
         std = 1.0
         fan_in = 1

@@ -89,11 +89,7 @@ def moe_apply_a2a(p: dict, x: jax.Array, cfg: ModelConfig, mesh,
     specs = dict(in_specs=(batch_spec, P(), P(expert_axis), P(expert_axis),
                            P(expert_axis)),
                  out_specs=batch_spec)
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(shard_fn, mesh=mesh, check_vma=False, **specs)
-    else:  # jax <= 0.4.x spelling
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(shard_fn, mesh=mesh, check_rep=False, **specs)
+    fn = jax.shard_map(shard_fn, mesh=mesh, check_vma=False, **specs)
     out = fn(x, p["router"], p["w1"].astype(cd), p["w3"].astype(cd),
              p["w2"].astype(cd))
     if m.num_shared_experts:

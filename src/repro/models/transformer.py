@@ -42,7 +42,7 @@ def _stack_specs(specs: dict, n: int) -> dict:
     """Prepend a stacked 'layers' dim to every spec in the tree."""
     def one(s: ParamSpec) -> ParamSpec:
         return ParamSpec((n,) + s.shape, ("layers",) + s.axes, init=s.init,
-                         scale=s.scale, dtype=s.dtype)
+                         scale=s.scale, dtype=s.dtype, fan_in=s.fan_in)
     return spec_tree_map(one, specs)
 
 
@@ -284,14 +284,19 @@ class Model:
 
     # --------------------------------------------------------------- forward
     def forward(self, params, tokens=None, embeds=None, cache=None,
-                cache_len=0, mode="train", logits_slice: int | None = None):
+                cache_len=0, mode="train", logits_slice: int | None = None,
+                logits_at=None):
         """Returns (logits, new_cache). ``logits_slice=k`` keeps only the
-        last k positions' logits (serving: k=1)."""
+        last k positions' logits (serving: k=1); ``logits_at=i`` (may be
+        traced) keeps only position i's, so a padded chunk compiles once
+        whatever its real length."""
         cfg = self.cfg
         from repro.models.common import cast_params
         params = cast_params(params, self.specs(), cfg.compute_dtype)
         x, new_cache = self._backbone(params, tokens, embeds, cache, cache_len, mode)
-        if logits_slice is not None:
+        if logits_at is not None:
+            x = jax.lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)
+        elif logits_slice is not None:
             x = x[:, -logits_slice:]
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))

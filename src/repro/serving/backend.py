@@ -5,9 +5,12 @@
 - ``JaxModelBackend`` (here): REAL model execution over a
   :class:`~repro.serving.paged_runtime.PagedKVRuntime`. Every prefill
   chunk and decode token runs through the model with the program's KV in
-  refcounted physical pages; step duration is measured wall time. On TPU
-  this is the production path (with the Pallas kernels); on CPU it demos
-  end-to-end generation with small models (examples/quickstart.py).
+  refcounted physical pages; step duration is the wall time until the
+  device has finished the step, less the time JAX spent compiling in it
+  (a warm server compiles nothing; :func:`compile_stats` reports it). On
+  a TPU this is the production path (compiled Pallas kernels); on the
+  CPU it runs small models with interpreted kernels
+  (examples/quickstart.py).
 
 The scheduler/TTL logic is identical under both backends — that is the
 point: the paper's contribution is exercised unchanged.
@@ -37,10 +40,85 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.kernels.page_copy import gather_pages
-from repro.serving.paged_runtime import PAGED_FAMILIES, PagedKVRuntime
+from repro.serving.paged_runtime import PAGED_FAMILIES, PagedKVRuntime, _pow2
 from repro.serving.prefix import (PrefixConfig, RadixPrefixIndex,
                                   request_block_hashes)
+
+_COMPILE_EVENTS = frozenset((
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration"))   # includes cache reads
+_compile = {"seconds": 0.0, "cache_hits": 0, "cache_misses": 0}
+_spans: list[tuple[float, float]] = []     # compile spans not yet counted
+_watching: list[bool] = []
+
+
+def _on_span(event: str, start: float, end: float, **_) -> None:
+    if event in _COMPILE_EVENTS:
+        _spans.append((start, end))
+
+
+def _on_event(event: str, **_) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _compile["cache_hits"] += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        _compile["cache_misses"] += 1
+
+
+def compile_stats() -> dict:
+    """This process's JAX compile work since the first JaxModelBackend:
+    wall seconds spent tracing, lowering and compiling (or reading the
+    persistent cache) — nested spans, such as an inner jit traced inside
+    an outer one, counted once — and persistent-cache hits and misses."""
+    if _spans:
+        spans = sorted(_spans)
+        _spans.clear()
+        lo, hi = spans[0]
+        for s, e in spans[1:]:
+            if s > hi:
+                _compile["seconds"] += hi - lo
+                lo = s
+            hi = max(hi, e)
+        _compile["seconds"] += hi - lo
+    return dict(_compile)
+
+
+def _watch_compiles() -> None:
+    if not _watching:
+        _watching.append(True)
+        jax.monitoring.register_event_time_span_listener(_on_span)
+        jax.monitoring.register_event_listener(_on_event)
+
+
+def kv_pool_budget(cfg: ModelConfig, *, limit: float, held_bytes: float,
+                   max_len: int, chunk: int, max_batch: int,
+                   extra_pages: int, page_size: int) -> float:
+    """Bytes of KV pool P (k and v, one copy) that fit in ``limit``
+    device bytes beside everything else the served path holds. With W
+    the weights as held (``held_bytes``: their real dtype) and C their
+    compute-dtype copy, which every step casts:
+
+    - decode step: W + 2P + C + batch logits — it returns new pools while
+      the old ones are alive (nothing is donated); so do a restore and a
+      COW split, with less beside them;
+    - prefill forward: W + P + C + scratch (in and out) + the extend
+      attention scores; its page write: W + 2P + scratch.
+
+    P is the largest pool meeting all three inside 90% of ``limit`` (the
+    rest for the allocator and small buffers), less ``extra_pages`` pages
+    the runtime adds past the budget (COW headroom)."""
+    kv_item = jnp.dtype(cfg.kv_cache_dtype or cfg.compute_dtype).itemsize
+    cast = cfg.param_count() * jnp.dtype(cfg.compute_dtype).itemsize
+    T = _pow2(max_len)                                 # scratch bucket cap
+    kv_per_pos = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim \
+        * kv_item                                      # k + v
+    scratch = 2 * T * kv_per_pos                       # in + out
+    scores = 2 * min(chunk, T) * T * cfg.num_heads * 4  # f32 s and p
+    logits = 2 * max_batch * cfg.vocab_size * 4
+    free = 0.9 * limit - held_bytes
+    pool = min((free - max(cast + logits, scratch)) / 2,
+               free - cast - scratch - scores)
+    return max(pool - extra_pages * page_size * kv_per_pos, 0.0)
 
 
 class JaxModelBackend:
@@ -53,6 +131,7 @@ class JaxModelBackend:
                  max_len: int = 4096, runtime: PagedKVRuntime | None = None,
                  n_pages: Optional[int] = None, page_size: int = 16,
                  interpret: bool | None = None):
+        _watch_compiles()
         if runtime is None:
             if cfg.family not in PAGED_FAMILIES or \
                     cfg.local_global_alternating:
@@ -70,7 +149,7 @@ class JaxModelBackend:
         self.params = params if params is not None else self.model.init(rng)
         self.max_len = max_len
         self._rng = rng
-        self._streams: dict[str, jax.Array] = {}   # stream -> token ids
+        self._streams: dict[str, np.ndarray] = {}  # stream -> token ids
         # staged-out host copies: program_id -> (np k, np v, tokens); the
         # buffers are the page_copy staging layout (L, pages, page, KV, Dh)
         self.host_caches: dict[str, tuple] = {}
@@ -86,6 +165,21 @@ class JaxModelBackend:
         # differential harness: verify every restore round-trips bit-exact
         self.verify_staging = False
         self.staging_checks: list[tuple[str, bool]] = []
+        self.step_seconds: list[float] = []   # device-synchronised steps
+
+    # ------------------------------------------------------- memory budget
+    def default_kv_budget(self, hw, chunk: int, max_batch: int,
+                          extra_pages: int) -> float:
+        """:func:`kv_pool_budget` for this backend on its device: the
+        limit is ``bytes_limit`` from ``memory_stats()`` where the device
+        reports one, else ``hw.hbm_bytes``; the weights count at the
+        bytes they occupy."""
+        stats = jax.devices()[0].memory_stats() or {}
+        return kv_pool_budget(
+            self.cfg, limit=float(stats.get("bytes_limit") or hw.hbm_bytes),
+            held_bytes=sum(x.nbytes for x in jax.tree.leaves(self.params)),
+            max_len=self.max_len, chunk=chunk, max_batch=max_batch,
+            extra_pages=extra_pages, page_size=self.runtime.page_size)
 
     # --------------------------------------------------- physical sharing
     def enable_prefix_sharing(self) -> RadixPrefixIndex:
@@ -125,20 +219,21 @@ class JaxModelBackend:
         return self.prefix_index.evict_chain(hashes, keep_blocks)
 
     # ------------------------------------------------------ token streams
-    def _stream(self, name: str) -> jax.Array:
+    def _stream(self, name: str) -> np.ndarray:
         """Deterministic token ids for a content stream, one id per
         absolute position (stable across turns and across programs that
-        share the stream)."""
+        share the stream); kept on the host, where slicing compiles
+        nothing."""
         s = self._streams.get(name)
         if s is None:
             key = jax.random.fold_in(
                 self._rng, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-            s = jax.random.randint(key, (self.max_len,), 0,
-                                   self.cfg.vocab_size)
+            s = np.asarray(jax.random.randint(key, (self.max_len,), 0,
+                                              self.cfg.vocab_size))
             self._streams[name] = s
         return s
 
-    def prompt_tokens(self, req, start: int, end: int) -> jax.Array:
+    def prompt_tokens(self, req, start: int, end: int) -> np.ndarray:
         """Prompt ids for positions [start, end): positions inside the
         shared preamble draw from the shared stream — the physical basis
         for COW sharing — the rest from the program's own stream."""
@@ -151,7 +246,7 @@ class JaxModelBackend:
                          [start:min(end, shared)])
         if end > shared:
             parts.append(self._stream(req.program_id)[max(start, shared):end])
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        return parts[0] if len(parts) == 1 else np.concatenate(parts)
 
     # --------------------------------------------------- engine KV hooks
     def drop_program(self, program_id: str) -> None:
@@ -176,8 +271,7 @@ class JaxModelBackend:
         e = rt.programs.get(program_id)
         if e is None or e.length == 0:
             return
-        k, v, n = rt.stage_out(program_id)
-        self.host_caches[program_id] = (np.asarray(k), np.asarray(v), n)
+        self.host_caches[program_id] = rt.stage_out(program_id)
         rt.evict(program_id, force=True)
         self.demotions += 1
 
@@ -199,16 +293,11 @@ class JaxModelBackend:
         ps = self.runtime.page_size
         pages = math.ceil(n / ps)
         k, v = k[:, :pages], v[:, :pages]
-        ids = self.runtime.restore(program_id, jnp.asarray(k),
-                                   jnp.asarray(v), n)
+        ids = self.runtime.restore(program_id, k, v, n)
         if self.verify_staging:          # differential harness: bit-exact?
-            idsj = jnp.asarray(ids, jnp.int32)
-            back_k = gather_pages(self.runtime.k_pages, idsj,
-                                  interpret=self.runtime.interpret)
-            back_v = gather_pages(self.runtime.v_pages, idsj,
-                                  interpret=self.runtime.interpret)
-            ok = bool(np.array_equal(np.asarray(back_k), k)) and \
-                bool(np.array_equal(np.asarray(back_v), v))
+            back_k, back_v = self.runtime.read_pages(ids)
+            ok = bool(np.array_equal(back_k, k)) and \
+                bool(np.array_equal(back_v, v))
             self.staging_checks.append((program_id, ok))
         self.restores += 1
 
@@ -220,10 +309,7 @@ class JaxModelBackend:
     def _bucket(n: int) -> int:
         """Pad chunk lengths to powers of two: bounds XLA recompilation to
         O(log max_chunk) shapes (the TPU serving constraint)."""
-        b = 16
-        while b < n:
-            b *= 2
-        return b
+        return max(16, _pow2(n))
 
     def _materialize(self, req, target: int, expected: int) -> None:
         """Ensure the program's pages cover [0, target) — recompute any
@@ -251,7 +337,8 @@ class JaxModelBackend:
                 self.shortfall_tokens += min(target, expected) - start
 
     def execute(self, prefill, decode) -> float:
-        t0 = time.time()
+        t0 = time.perf_counter()
+        c0 = compile_stats()["seconds"]
         rt = self.runtime
         self._step += 1
         now = float(self._step)
@@ -289,4 +376,11 @@ class JaxModelBackend:
             # (bit-identical to the per-program loop — see decode_batch)
             rt.decode_batch(self.params, decode_pids)
             self.decode_tokens_computed += len(decode_pids)
-        return max(time.time() - t0, 1e-6)
+        # the step ends when the device is done: the pools are the last
+        # thing every step writes, the next tokens the last it reads
+        jax.block_until_ready((rt.k_pages, rt.v_pages,
+                               [rt._last[p] for p in decode_pids]))
+        dt = time.perf_counter() - t0 - (compile_stats()["seconds"] - c0)
+        dt = max(dt, 1e-6)
+        self.step_seconds.append(dt)
+        return dt

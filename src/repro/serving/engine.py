@@ -127,8 +127,19 @@ class Engine:
         self.backend = backend or SimBackend(self.cost)
 
         # --- KV block pool sizing ---
-        kv_budget = ecfg.kv_budget_bytes or max(
-            hw.hbm_bytes * ecfg.chips * 0.9 - self.profile.param_bytes, 1e9)
+        # a batched decode step may COW-split one shared append page per
+        # batch member before any accounting-side eviction can run: the
+        # physical pool carries that many pages past the accounting pool
+        cow_headroom = max(16, ecfg.max_batch)
+        if ecfg.kv_budget_bytes:
+            kv_budget = ecfg.kv_budget_bytes
+        elif hasattr(self.backend, "default_kv_budget"):
+            # a real device: what actually fits next to the held weights
+            kv_budget = self.backend.default_kv_budget(
+                hw, ecfg.chunk_size, ecfg.max_batch, cow_headroom)
+        else:
+            kv_budget = max(hw.hbm_bytes * ecfg.chips * 0.9
+                            - self.profile.param_bytes, 1e9)
         kvpt = self.profile.kv_bytes_per_token
         if kvpt > 0:
             block_bytes = ecfg.block_size * kvpt
@@ -204,10 +215,7 @@ class Engine:
                     f"backend page_size {runtime.page_size} != engine "
                     f"block_size {ecfg.block_size}: physical pages and "
                     f"accounting blocks must be the same granularity")
-            # headroom beyond the accounting pool: a batched decode step
-            # may COW-split one shared append page per batch member
-            # before any accounting-side eviction can run
-            runtime.grow(self.blocks.total + max(16, ecfg.max_batch))
+            runtime.grow(self.blocks.total + cow_headroom)
             if self.prefix_index is not None \
                     and hasattr(self.backend, "enable_prefix_sharing"):
                 self.backend.enable_prefix_sharing()

@@ -25,6 +25,7 @@ block tables; on CPU: interpret mode).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -48,6 +49,50 @@ from repro.models.transformer import Model
 PAGED_FAMILIES = ("dense", "moe", "audio", "vlm")
 
 
+def _pow2(n: int) -> int:
+    return 1 << max(0, n - 1).bit_length()
+
+
+def _pad_ids(ids: list[int], n: int, *rest: list[int]) -> list[np.ndarray]:
+    """Pad index lists to length ``n`` by repeating their last entry, so
+    the padded slots redo the last real copy (same source, same target,
+    same bytes) and a bucketed shape changes nothing."""
+    return [np.asarray(x + x[-1:] * (n - len(x)), np.int32)
+            for x in (ids,) + rest]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gather_span(cache_k, cache_v, k_pages, v_pages, ids, *, interpret):
+    """Pages ``ids`` (n,) -> scratch positions [0, n*page) of the
+    contiguous (L, 1, T, KV, Dh) caches, through the page_copy gather."""
+    def one(cache, pages):
+        L, _, page, KV, Dh = pages.shape
+        g = gather_pages(pages, ids, interpret=interpret)
+        g = g.reshape(L, 1, ids.shape[0] * page, KV, Dh).astype(cache.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(cache, g, 0, axis=2)
+    return one(cache_k, k_pages), one(cache_v, v_pages)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _scatter_span(k_pages, v_pages, cache_k, cache_v, ids, blocks, lo, hi, *,
+                  interpret):
+    """Scratch positions [lo, hi) -> physical pages: ``ids`` (n,) hold the
+    program's logical blocks ``blocks`` (n,). Positions of those pages
+    outside [lo, hi) keep the pool's bytes (gathered, merged, scattered
+    back through the page_copy kernels)."""
+    page = k_pages.shape[2]
+    pos = blocks[:, None] * page + jnp.arange(page)          # (n, page)
+    live = ((pos >= lo) & (pos < hi))[None, :, :, None, None]
+    src = jnp.clip(pos, 0, cache_k.shape[2] - 1)
+
+    def one(pages, cache):
+        new = cache[:, 0][:, src].astype(pages.dtype)    # (L, n, page, KV, Dh)
+        old = gather_pages(pages, ids, interpret=interpret)
+        return scatter_pages(pages, jnp.where(live, new, old), ids,
+                             interpret=interpret)
+    return one(k_pages, cache_k), one(v_pages, cache_v)
+
+
 @dataclasses.dataclass
 class ProgramEntry:
     pages: list[int]
@@ -67,6 +112,8 @@ class PagedKVRuntime:
         self.interpret = resolve_interpret(interpret)
         # one jitted batched decode step; jax.jit retraces per (B, n_tab)
         self._decode_step = jax.jit(self._decode_step_impl)
+        # one jitted prefill forward per (chunk bucket, scratch bucket, mode)
+        self._forward = jax.jit(self.model.forward, static_argnames=("mode",))
         L, KV, Dh = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
         dt = jnp.dtype(cfg.kv_cache_dtype or cfg.compute_dtype)
         self.k_pages = jnp.zeros((L, n_pages, page_size, KV, Dh), dt)
@@ -128,11 +175,20 @@ class PagedKVRuntime:
         extra = n_pages_total - self.n_pages
         if extra <= 0:
             return
-        pad = (self.k_pages.shape[0], extra) + self.k_pages.shape[2:]
-        self.k_pages = jnp.concatenate(
-            [self.k_pages, jnp.zeros(pad, self.k_pages.dtype)], axis=1)
-        self.v_pages = jnp.concatenate(
-            [self.v_pages, jnp.zeros(pad, self.v_pages.dtype)], axis=1)
+        if self.refs:
+            # live pages: one pad per pool, old + new held only briefly
+            pad = ((0, 0), (0, extra), (0, 0), (0, 0), (0, 0))
+            self.k_pages = jnp.pad(self.k_pages, pad)
+            self.v_pages = jnp.pad(self.v_pages, pad)
+        else:
+            # nothing held: drop the old pools before allocating, so the
+            # device never holds both
+            shape = (self.k_pages.shape[0], n_pages_total) \
+                + self.k_pages.shape[2:]
+            dt = self.k_pages.dtype
+            self.k_pages = self.v_pages = None
+            self.k_pages = jnp.zeros(shape, dt)
+            self.v_pages = jnp.zeros(shape, dt)
         self.free.extend(range(self.n_pages, n_pages_total))
         self.n_pages = n_pages_total
 
@@ -268,22 +324,31 @@ class PagedKVRuntime:
         return deduped
 
     # ------------------------------------------------------- tier staging
-    def stage_out(self, program_id: str) -> tuple[jax.Array, jax.Array, int]:
-        """Batch-gather the program's scattered pages into contiguous
-        (L, n, page, KV, Dh) staging buffers — the unit a tier move DMAs
-        to host DRAM in one transfer."""
+    def read_pages(self, ids: list[int]) -> tuple[np.ndarray, np.ndarray]:
+        """Batch-gather pages ``ids`` into contiguous (L, n, page, KV, Dh)
+        host buffers: one gather kernel per pool, at a power-of-two
+        width so tier moves compile O(log) shapes, then one D2H copy."""
+        (pad,) = _pad_ids(list(ids), _pow2(len(ids)))
+        pad = jnp.asarray(pad)
+        k = gather_pages(self.k_pages, pad, interpret=self.interpret)
+        v = gather_pages(self.v_pages, pad, interpret=self.interpret)
+        return np.asarray(k)[:, :len(ids)], np.asarray(v)[:, :len(ids)]
+
+    def stage_out(self, program_id: str
+                  ) -> tuple[np.ndarray, np.ndarray, int]:
+        """The program's scattered pages as contiguous host staging
+        buffers (:meth:`read_pages`) — the unit a tier move DMAs to host
+        DRAM in one transfer — and its length."""
         e = self.programs[program_id]
-        ids = jnp.asarray(e.pages, jnp.int32)
         self._obs_event("stage_out", program_id, {"pages": len(e.pages),
                                                   "length": e.length})
-        return (gather_pages(self.k_pages, ids, interpret=self.interpret),
-                gather_pages(self.v_pages, ids, interpret=self.interpret),
-                e.length)
+        return (*self.read_pages(e.pages), e.length)
 
     def restore(self, program_id: str, k_staging, v_staging,
                 length: int) -> list[int]:
-        """Scatter reloaded contiguous staging buffers into freshly
-        allocated physical pages (the H2D leg of a promotion)."""
+        """Scatter reloaded contiguous (host) staging buffers into freshly
+        allocated physical pages (the H2D leg of a promotion), padded to a
+        power-of-two width by repeating the last page."""
         stale = self.programs.pop(program_id, None)
         if stale is not None:           # defensive: never leak pages
             for pi in stale.pages:
@@ -297,11 +362,14 @@ class PagedKVRuntime:
             for pi in pages:
                 self._deref(pi)
             raise
-        ids = jnp.asarray(pages, jnp.int32)
-        self.k_pages = scatter_pages(self.k_pages, k_staging, ids,
-                                     interpret=self.interpret)
-        self.v_pages = scatter_pages(self.v_pages, v_staging, ids,
-                                     interpret=self.interpret)
+        (ids,) = _pad_ids(pages, _pow2(n))
+        take = np.minimum(np.arange(len(ids)), n - 1)
+        self.k_pages = scatter_pages(
+            self.k_pages, jnp.asarray(np.asarray(k_staging)[:, take]),
+            jnp.asarray(ids), interpret=self.interpret)
+        self.v_pages = scatter_pages(
+            self.v_pages, jnp.asarray(np.asarray(v_staging)[:, take]),
+            jnp.asarray(ids), interpret=self.interpret)
         self.programs[program_id] = ProgramEntry(pages, length)
         self._obs_event("restore", program_id, {"pages": len(pages),
                                                 "length": length})
@@ -320,26 +388,26 @@ class PagedKVRuntime:
         attention makes the trailing junk tokens invisible to the real
         ones, and only the real KV is scattered into pages) — callers use
         power-of-two buckets to bound XLA recompilation to
-        O(log max_chunk) shapes, the TPU serving constraint."""
-        cfg = self.cfg
+        O(log max_chunk) shapes, the TPU serving constraint. The scratch
+        length is bucketed the same way; near a bucket's end the padding
+        is trimmed rather than the scratch doubled."""
+        tokens = np.asarray(tokens, np.int32)     # padded on the host
         S = tokens.shape[-1]
-        Sp = max(pad_to, S) if pad_to is not None else S
-        if Sp > S:
-            tokens = jnp.concatenate(
-                [tokens, jnp.zeros((Sp - S,), tokens.dtype)])
         e = self.programs.setdefault(program_id, ProgramEntry([], 0))
         start = e.length
         self._ensure_capacity(e, start + S)       # pages for REAL tokens only
-        cap = len(e.pages) * self.page_size
-        cache = self.model.init_cache(1, max(cap, start + Sp))
+        T = _pow2(max(len(e.pages) * self.page_size, start + S))
+        Sp = min(max(pad_to or S, S), T - start)
+        tokens = np.pad(tokens, (0, Sp - S))
+        cache = self.model.init_cache(1, T)
         if start:
             # re-materialize existing pages into the contiguous scratch
             cache = self._gather_into(cache, e)
-        # keep logits from the last real position onward (Sp - S + 1 rows)
-        logits, cache = self.model.forward(
+        logits, cache = self._forward(
             params, tokens=tokens.reshape(1, Sp), cache=cache,
             cache_len=jnp.asarray(start, jnp.int32),
-            mode="extend" if start else "prefill", logits_slice=Sp - S + 1)
+            mode="extend" if start else "prefill",
+            logits_at=jnp.asarray(S - 1, jnp.int32))
         self._scatter_from(cache, e, start, S)
         e.length = start + S
         self._last[program_id] = jnp.argmax(logits[0, 0]).astype(jnp.int32)
@@ -348,29 +416,26 @@ class PagedKVRuntime:
     def _scatter_from(self, cache, e: ProgramEntry, start: int, count: int):
         """Copy cache[k/v][:, 0, start:start+count] into physical pages."""
         ps = self.page_size
-        k = cache["k"][:, 0]                       # (L, cap, KV, Dh)
-        v = cache["v"][:, 0]
-        pos = start
-        while pos < start + count:
-            off = pos % ps                 # mid-page when adoption was capped
-            n = min(ps - off, start + count - pos)
-            pi = self._writable_page(e, pos // ps)  # COW-split if shared
-            kblk = k[:, pos:pos + n].astype(self.k_pages.dtype)
-            vblk = v[:, pos:pos + n].astype(self.v_pages.dtype)
-            self.k_pages = self.k_pages.at[:, pi, off:off + n].set(kblk)
-            self.v_pages = self.v_pages.at[:, pi, off:off + n].set(vblk)
-            pos += n
+        blocks = list(range(start // ps, (start + count - 1) // ps + 1))
+        ids = [self._writable_page(e, b) for b in blocks]  # COW-split shared
+        ids, blocks = _pad_ids(ids, _pow2(len(ids)), blocks)
+        self.k_pages, self.v_pages = _scatter_span(
+            self.k_pages, self.v_pages, cache["k"], cache["v"],
+            jnp.asarray(ids), jnp.asarray(blocks),
+            jnp.asarray(start, jnp.int32),
+            jnp.asarray(start + count, jnp.int32), interpret=self.interpret)
 
     def _gather_into(self, cache, e: ProgramEntry):
+        """Pages covering [0, e.length) -> the scratch's leading positions.
+        Positions from e.length on may receive stale page bytes: the
+        forward overwrites the chunk's own span and masks the rest."""
         ps = self.page_size
-        for i, pi in enumerate(e.pages):
-            n = min(ps, e.length - i * ps)
-            if n <= 0:
-                break
-            cache["k"] = cache["k"].at[:, 0, i * ps:i * ps + n].set(
-                self.k_pages[:, pi, :n].astype(cache["k"].dtype))
-            cache["v"] = cache["v"].at[:, 0, i * ps:i * ps + n].set(
-                self.v_pages[:, pi, :n].astype(cache["v"].dtype))
+        n = math.ceil(e.length / ps)
+        T = cache["k"].shape[2]
+        (ids,) = _pad_ids(e.pages[:n], min(_pow2(n), T // ps))
+        cache["k"], cache["v"] = _gather_span(
+            cache["k"], cache["v"], self.k_pages, self.v_pages,
+            jnp.asarray(ids), interpret=self.interpret)
         return cache
 
     # ------------------------------------------------------------ decode

@@ -1,13 +1,14 @@
 """Offline profiling + analytic step-cost model (paper §5.2, TPU-adapted).
 
 The paper profiles (1) GPU↔CPU offload bandwidth and (2) a prefill-vs-
-context quadratic, per (hardware, model) pair, in <10 min. This container
-has no accelerator, so the *measurements* come from a roofline model of the
-target chip (v5e: 197 TFLOP/s bf16, 819 GB/s HBM); the *method* — sampling
-chunk sizes {1k, 2k, 4k, ...} and fitting a quadratic — is reproduced
-faithfully, and on real hardware `measure_fn` is swapped for timed runs.
+context quadratic, per (hardware, model) pair, in <10 min. Here the
+*measurements* come from a roofline model of the chip, priced with its
+published peaks (:data:`DEVICE_PROFILES`); the *method* — sampling chunk
+sizes {1k, 2k, 4k, ...} and fitting a quadratic — is reproduced
+faithfully, and `measure_fn` can be swapped for timed runs.
 
-The same cost model drives the virtual-clock execution backend.
+The same cost model drives the virtual-clock execution backend, whose
+virtual chip is the default :class:`HardwareProfile` (a v5e).
 """
 from __future__ import annotations
 
@@ -31,6 +32,27 @@ class HardwareProfile:
     ssd_bw: float = 3e9
     mfu: float = 0.5                 # achievable fraction for prefill
     decode_eff: float = 0.7          # achievable fraction of HBM bw
+
+
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: TPU v5e ("TPU v5 lite"): 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s
+#: (Google Cloud documentation, "TPU v5e"). Only the peaks are published;
+#: ``mfu``/``decode_eff`` and the link bandwidths are model assumptions.
+DEVICE_PROFILES: dict[str, HardwareProfile] = {
+    "TPU v5 lite": HardwareProfile(name="tpu-v5e", flops=197e12,
+                                   hbm_bw=819e9, hbm_bytes=16e9),
+}
+
+
+def hardware_profile(device_kind: str) -> HardwareProfile:
+    """The :data:`DEVICE_PROFILES` row for ``device_kind``. A kind without
+    published peaks is an error, never a default."""
+    try:
+        return DEVICE_PROFILES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add a "
+            f"DEVICE_PROFILES row with its source") from None
 
 
 @dataclasses.dataclass

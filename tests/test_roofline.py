@@ -13,12 +13,6 @@ def analyze(fn, *args):
     return HLOAnalyzer(compiled.as_text()), compiled
 
 
-def xla_cost(compiled) -> dict:
-    """cost_analysis() returns a one-element list on some jax versions."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 class TestFlops:
     def test_plain_matmul_matches_cost_analysis(self):
         a = jnp.ones((256, 512), jnp.float32)
@@ -27,7 +21,7 @@ class TestFlops:
         mine = ana.entry_cost().flops
         expect = 2 * 256 * 512 * 128
         assert abs(mine - expect) / expect < 0.05
-        xla = xla_cost(compiled).get("flops", 0)
+        xla = compiled.cost_analysis().get("flops", 0)
         assert abs(mine - xla) / max(xla, 1) < 0.1
 
     def test_scan_multiplies_trip_count(self):
@@ -46,7 +40,7 @@ class TestFlops:
         mine = ana.entry_cost().flops
         expect = n_iter * 2 * 64 * 64 * 64
         assert abs(mine - expect) / expect < 0.1
-        xla = xla_cost(compiled).get("flops", 0)
+        xla = compiled.cost_analysis().get("flops", 0)
         assert xla < mine / 2                    # XLA undercounts scans
 
     def test_batch_dot(self):
