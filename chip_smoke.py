@@ -75,15 +75,16 @@ TOOLS = (("web_search", 0.45, 1.5, 0.9), ("fetch_url", 0.35, 0.8, 1.8),
          ("calculator", 0.1, 0.05, 0.4), ("finish", 0.1, 0.3, 0.6))
 
 #: served logits (bf16 weights, activations and KV) against the float32
-#: reference, as max |error| over the reference's largest |logit|. bf16
-#: keeps 8 mantissa bits; through 28 layers the error measured 1.2-2.2%
-#: on narrow 28-layer configs on the CPU. A page or position mix-up moves
-#: logits by tens of percent.
-REF_REL_TOL = 5e-2
-
-#: the smoke config's 2 layers keep bf16 error under 1% of the largest
-#: |logit| (measured on the CPU), so the rehearsal is held tighter
-SMOKE_REF_REL_TOL = 2e-2
+#: reference, as max |error| over the reference's largest |logit|. On a
+#: v5e the full-width run measured 0.008 at worst; the smoke config and
+#: a narrow 28-layer qwen2 measured 0.005 and 0.019 on the CPU. Random
+#: weights attend diffusely, so faults move logits little: on that narrow
+#: config (CPU) a decode one position late read 0.030-0.036 and a table
+#: slot pointing at an unwritten page 0.070-0.076, while pages swapped
+#: within a program are invisible (attention is order-free). At full
+#: width on a v5e (3,000-token prompt) the first two read 0.016-0.018 and
+#: 0.010-0.011 and pass: this check catches gross faults only.
+REF_REL_TOL = 2e-2
 
 
 def log(msg: str) -> None:
@@ -243,7 +244,8 @@ def main() -> int:
     for a in range(0, len(toks), sz.chunk):
         chunk = toks[a:a + sz.chunk]
         logits = rt.prefill(be.params, pid, chunk,
-                            pad_to=1 << (len(chunk) - 1).bit_length())
+                            pad_to=1 << (len(chunk) - 1).bit_length(),
+                            max_len=be.max_len)
     outs, fed = [np.asarray(logits)], []
     for _ in range(sz.ref_decode):
         fed.append(int(rt._last_token(be.params, pid)))
@@ -251,15 +253,14 @@ def main() -> int:
     rt.evict(pid, force=True)
     ref = reference_logits(cfg, be.params, np.concatenate([toks, fed]),
                            sz.ref_decode + 1)
-    tol = SMOKE_REF_REL_TOL if args.smoke else REF_REL_TOL
-    checks = [compare_logits(o, r, tol) for o, r in zip(outs, ref)]
+    checks = [compare_logits(o, r, REF_REL_TOL) for o, r in zip(outs, ref)]
     phase("reference", t0, c0)
     worst = max(c["rel_err"] for c in checks)
     log(f"reference: {len(toks)}-token prompt, prefill + "
         f"{sz.ref_decode} decode steps vs float32 highest-precision "
         f"forward: rel err per position "
         f"{[round(c['rel_err'], 6) for c in checks]}, worst {worst:.6f} "
-        f"(limit {tol})")
+        f"(limit {REF_REL_TOL})")
     if not all(c["ok"] for c in checks):
         fail(f"served logits off the float32 reference: worst {worst}")
 

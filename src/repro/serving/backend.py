@@ -331,7 +331,8 @@ class JaxModelBackend:
         if start < target:
             toks = self.prompt_tokens(req, start, target)
             rt.prefill(self.params, req.program_id, toks,
-                       pad_to=self._bucket(target - start))
+                       pad_to=self._bucket(target - start),
+                       max_len=self.max_len)
             self.prefill_tokens_computed += target - start
             if start < min(target, expected):
                 self.shortfall_tokens += min(target, expected) - start

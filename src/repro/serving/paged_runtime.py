@@ -171,24 +171,17 @@ class PagedKVRuntime:
         already at least that big). The engine calls this at wiring time
         so the page pool covers its accounting block pool 1:1 — the
         BlockManager's admission control then guarantees the runtime
-        never OOMs before accounting does."""
-        extra = n_pages_total - self.n_pages
-        if extra <= 0:
+        never OOMs before accounting does. The pools hold nothing yet, so
+        they are dropped before the larger ones are allocated: the device
+        never holds both."""
+        if n_pages_total <= self.n_pages:
             return
-        if self.refs:
-            # live pages: one pad per pool, old + new held only briefly
-            pad = ((0, 0), (0, extra), (0, 0), (0, 0), (0, 0))
-            self.k_pages = jnp.pad(self.k_pages, pad)
-            self.v_pages = jnp.pad(self.v_pages, pad)
-        else:
-            # nothing held: drop the old pools before allocating, so the
-            # device never holds both
-            shape = (self.k_pages.shape[0], n_pages_total) \
-                + self.k_pages.shape[2:]
-            dt = self.k_pages.dtype
-            self.k_pages = self.v_pages = None
-            self.k_pages = jnp.zeros(shape, dt)
-            self.v_pages = jnp.zeros(shape, dt)
+        assert not self.refs, "grow() is a wiring-time call: pages are held"
+        shape = (self.k_pages.shape[0], n_pages_total) + self.k_pages.shape[2:]
+        dt = self.k_pages.dtype
+        self.k_pages = self.v_pages = None
+        self.k_pages = jnp.zeros(shape, dt)
+        self.v_pages = jnp.zeros(shape, dt)
         self.free.extend(range(self.n_pages, n_pages_total))
         self.n_pages = n_pages_total
 
@@ -377,7 +370,8 @@ class PagedKVRuntime:
 
     # ----------------------------------------------------------- prefill
     def prefill(self, params, program_id: str, tokens: jax.Array,
-                pad_to: Optional[int] = None) -> jax.Array:
+                pad_to: Optional[int] = None,
+                max_len: Optional[int] = None) -> jax.Array:
         """Run the model's prefill and scatter the contiguous per-layer KV
         into this program's (scattered) physical pages. Returns the final
         *real* position's logits and seeds the program's greedy
@@ -389,15 +383,28 @@ class PagedKVRuntime:
         ones, and only the real KV is scattered into pages) — callers use
         power-of-two buckets to bound XLA recompilation to
         O(log max_chunk) shapes, the TPU serving constraint. The scratch
-        length is bucketed the same way; near a bucket's end the padding
-        is trimmed rather than the scratch doubled."""
+        is the power-of-two bucket of the padded end, capped at that of
+        ``max_len`` (the longest program the caller holds, which prices
+        the scratch). A padded chunk that would overrun the cap runs as
+        exact power-of-two pieces instead, so every forward shape is a
+        pair of powers of two, however the chunks fall."""
         tokens = np.asarray(tokens, np.int32)     # padded on the host
         S = tokens.shape[-1]
         e = self.programs.setdefault(program_id, ProgramEntry([], 0))
         start = e.length
+        Sp = max(pad_to or S, S)
+        if max_len is not None and start + Sp > _pow2(max_len):
+            assert start + S <= max_len, (start, S, max_len)
+            i = 0
+            for bit in reversed(range(S.bit_length())):
+                if S >> bit & 1:
+                    logits = self.prefill(params, program_id,
+                                          tokens[i:i + (1 << bit)],
+                                          max_len=max_len)
+                    i += 1 << bit
+            return logits
         self._ensure_capacity(e, start + S)       # pages for REAL tokens only
-        T = _pow2(max(len(e.pages) * self.page_size, start + S))
-        Sp = min(max(pad_to or S, S), T - start)
+        T = _pow2(max(len(e.pages) * self.page_size, start + Sp))
         tokens = np.pad(tokens, (0, Sp - S))
         cache = self.model.init_cache(1, T)
         if start:

@@ -11,7 +11,6 @@ from repro.configs import ARCH_IDS, get_config
 from repro.models import Model
 
 
-
 @pytest.fixture(scope="module")
 def rng():
     return jax.random.PRNGKey(0)
@@ -38,14 +37,32 @@ def test_smoke_train_step(arch, rng):
     assert np.isfinite(gnorm) and gnorm > 0
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
-def test_prefill_decode_parity(arch, rng):
-    """Greedy serving path == full forward at every position. MoE archs
-    get a capacity no routing can overflow: static-capacity (GShard)
+#: archs whose smoke config routes through a capacity-bounded MoE
+MOE_ARCHS = [a for a in ARCH_IDS if get_config(a, smoke=True).moe is not None]
+
+#: configured capacity: the full forward dispatches all B*(S+extra)
+#: tokens at once and may drop a token the one-token decode serves (GShard
+#: semantics), removing that expert's share from the token. The affected
+#: rows moved by up to 0.44 of their largest |logit| (measured, seed 0:
+#: 0.207 moonshot, 0.440 qwen3-moe, both at the prefill position; the
+#: others stayed under 0.08); greedy tokens still agree everywhere
+CAPACITY_DROP_REL_TOL = 0.5
+
+
+@pytest.mark.parametrize(
+    "arch,capacity", [pytest.param(a, "no_drop", id=a) for a in ARCH_IDS]
+    + [pytest.param(a, "configured", id=f"{a}-configured")
+       for a in MOE_ARCHS])
+def test_prefill_decode_parity(arch, capacity, rng):
+    """Greedy serving path == full forward at every position. With
+    ``no_drop`` the MoE archs get a capacity no routing can overflow, so
+    the two paths must agree to float rounding (1e-3): static-capacity
     dispatch drops different tokens for different token counts, which is
-    a dispatch property, not a serving-path one."""
+    a dispatch property, not a serving-path one. ``configured`` keeps
+    the arch's own capacity factor and holds it to
+    CAPACITY_DROP_REL_TOL of each position's largest |logit|."""
     cfg = get_config(arch, smoke=True)
-    if cfg.moe is not None:
+    if cfg.moe is not None and capacity == "no_drop":
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=cfg.moe.num_experts / cfg.moe.top_k))
     model = Model(cfg)
@@ -55,22 +72,26 @@ def test_prefill_decode_parity(arch, rng):
                                 cfg.vocab_size)
     full_logits, _ = model.forward(params, tokens=tokens, mode="train")
 
+    def check(got, want):
+        got, want = np.asarray(got), np.asarray(want)
+        if capacity == "no_drop":
+            np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-3)
+            return
+        err = np.abs(got - want).max(-1) / np.abs(want).max(-1)
+        assert (err <= CAPACITY_DROP_REL_TOL).all(), err
+        assert (got.argmax(-1) == want.argmax(-1)).all()
+
     cache = model.init_cache(B, 64)
     pre, cache = model.forward(params, tokens=tokens[:, :S], cache=cache,
                                cache_len=0, mode="prefill", logits_slice=1)
-    tol = 1e-3
-    np.testing.assert_allclose(np.asarray(pre[:, 0]),
-                               np.asarray(full_logits[:, S - 1]),
-                               rtol=tol, atol=tol)
+    check(pre[:, 0], full_logits[:, S - 1])
     cl = S
     for i in range(extra):
         step_logits, cache = model.forward(
             params, tokens=tokens[:, S + i:S + i + 1], cache=cache,
             cache_len=jnp.full((B,), cl, jnp.int32), mode="decode",
             logits_slice=1)
-        np.testing.assert_allclose(np.asarray(step_logits[:, 0]),
-                                   np.asarray(full_logits[:, S + i]),
-                                   rtol=tol, atol=tol)
+        check(step_logits[:, 0], full_logits[:, S + i])
         cl += 1
 
 

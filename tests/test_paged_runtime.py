@@ -96,6 +96,74 @@ class TestPagedRuntime:
             rt.prefill(params, "p", tokens)
 
 
+class TestPrefillShapes:
+    """Prefill compiles one forward per (chunk, scratch) shape, so both
+    must come from a small fixed set however chunks and offsets fall."""
+
+    def test_chunked_programs_compile_pow2_shapes(self, setup):
+        """Many programs, chunked at assorted offsets (radix adoption
+        starts mid-page): every forward is a pair of powers of two with
+        the scratch at most max_len's bucket. Page moves are stubbed; only
+        the shapes handed to the forward are counted."""
+        from repro.serving.backend import JaxModelBackend
+        from repro.serving.paged_runtime import ProgramEntry
+        cfg, model, params = setup
+        max_len, page = 1000, 16
+        rt = PagedKVRuntime(cfg, n_pages=max_len // page + 1, page_size=page)
+        shapes = set()
+
+        def forward(params, *, tokens, cache, mode, **_):
+            shapes.add((tokens.shape[1], cache["k"].shape[2], mode))
+            return jnp.zeros((1, 1, cfg.vocab_size)), cache
+        rt._forward = forward
+        rt._gather_into = lambda cache, e: cache
+        rt._scatter_from = lambda cache, e, start, count: None
+        rs = np.random.default_rng(0)
+        for p in range(300):
+            n = int(rs.integers(2, max_len + 1))
+            pos = int(rs.integers(0, n)) if p % 2 else 0
+            rt.programs["p"] = ProgramEntry([], pos)
+            while pos < n:
+                chunk = min(int(rs.integers(1, 129)), n - pos)
+                rt.prefill(params, "p", np.zeros(chunk, np.int32),
+                           pad_to=JaxModelBackend._bucket(chunk),
+                           max_len=max_len)
+                pos += chunk
+                assert rt.programs["p"].length == pos
+            rt.evict("p")
+        pow2 = lambda x: x & (x - 1) == 0
+        assert all(pow2(s) and pow2(t) and s <= t <= 1024
+                   for s, t, _ in shapes), sorted(shapes)
+        # chunks 1..128 -> 8 widths, scratch 16..1024 -> 7 lengths
+        assert len(shapes) <= 8 * 7 * 2, len(shapes)
+        assert len(rt.free) == rt.n_pages
+
+    def test_chunk_at_max_len_cap_runs_as_pieces(self, setup):
+        """A padded chunk past max_len's bucket runs as exact
+        power-of-two pieces and gives the logits and the KV of one
+        unpadded prefill."""
+        cfg, model, params = setup
+        tokens = np.asarray(jax.random.randint(
+            jax.random.PRNGKey(9), (31,), 0, cfg.vocab_size))
+        rt = PagedKVRuntime(cfg, n_pages=8, page_size=8)
+        shapes = []
+        fwd = rt._forward
+
+        def record(params, **kw):
+            shapes.append((kw["tokens"].shape[1], kw["cache"]["k"].shape[2]))
+            return fwd(params, **kw)
+        rt._forward = record
+        rt.prefill(params, "p", tokens[:20], pad_to=32, max_len=32)
+        logits = rt.prefill(params, "p", tokens[20:], pad_to=16, max_len=32)
+        # 11 tokens at 20: a 16-wide chunk would end at 36 > 32
+        assert shapes == [(32, 32), (8, 32), (2, 32), (1, 32)], shapes
+        check = compare_logits(logits, reference_logits(cfg, params,
+                                                        tokens, 1)[0],
+                               REL_TOL)
+        assert check["ok"], check
+        assert_matches_reference(cfg, params, rt, "p", tokens, 1)
+
+
 class TestPinnedEvict:
     """Regression: evict() must refuse a pinned program (the TTL mechanism
     depends on pinned pages surviving) unless force=True."""
