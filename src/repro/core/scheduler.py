@@ -23,6 +23,7 @@ from typing import Callable, Optional
 from repro.core.policies import Policy
 from repro.core.tool_handler import ToolCallHandler
 from repro.core.types import Request, RequestState
+from repro.obs.spans import span
 from repro.serving.blocks import BlockManager
 from repro.serving.offload import OffloadManager
 from repro.serving.prefix import RadixPrefixIndex, request_block_hashes
@@ -39,6 +40,18 @@ def materialized_tokens(req: Request) -> int:
     if req.generated > 0:               # prefill done: prompt is resident
         return req.prompt_len + req.generated - 1
     return req.prefill_pos
+
+
+def _solve_inputs(decision) -> dict:
+    """The TTL solve's inputs behind a retention decision, where the
+    policy solved one (the audit records the same)."""
+    d = decision.meta
+    if d is None:
+        return {}
+    out = {"prefill_reload": d.prefill_reload, "t_bar": d.t_bar}
+    if d.queue_eta is not None:
+        out["queue_eta"] = d.queue_eta
+    return out
 
 
 @dataclasses.dataclass
@@ -91,9 +104,10 @@ class Scheduler:
         # tiered-store backend hooks: a demotion keeps the KV (host copy)
         # while an eviction genuinely loses it; a reload restores it
         # (on_reload receives the usable cached-token count — a partial
-        # prefix truncates the physical restore)
+        # prefix truncates the physical restore — and, as ``priced_s``,
+        # the reload seconds admission priced)
         self.on_demote: Optional[Callable[[str], None]] = None
-        self.on_reload: Optional[Callable[[str, int], None]] = None
+        self.on_reload: Optional[Callable[..., None]] = None
         # engine-wired estimator: prefill seconds for a token count (prices
         # the recompute a TTL/offload miss causes — bench/metrics signal)
         self.recompute_estimate_fn: Optional[Callable[[int], float]] = None
@@ -150,24 +164,30 @@ class Scheduler:
             return {"pinned": False, "ttl": 0.0}
 
         self.handler.func_call_finish(tool, now, req.program_id)
-        if self.obs is not None:
-            # stage the solve context: the TTL model itself knows neither
-            # the program nor the clock (see repro.obs.audit)
-            self.obs.audit.begin_solve(req.program_id, tool, req.turn_idx,
-                                       now, replica=self.obs_replica)
-        decision = self.policy.retention(req, tool, self.handler)
-        if decision.ttl > 0:
-            n = self.blocks.pin(req.request_id, req.program_id)
-            self.pinned[req.program_id] = PinEntry(
-                req.program_id, req.request_id, now + decision.ttl,
-                materialized_tokens(req), now,
-                prefix_node=req.prefix_node)   # pin inherits the radix lock
-            req.prefix_node = None
-            self.stats.pins += 1
-            self._log("pin", req.program_id, req.turn_idx,
-                      round(decision.ttl, 9))
-            return {"pinned": True, "ttl": decision.ttl, "blocks": n}
-        self._free_finished(req, now)
+        with span("sched.retention", program=req.program_id,
+                  turn=req.turn_idx, tool=tool) as sp:
+            if self.obs is not None:
+                # stage the solve context: the TTL model itself knows
+                # neither the program nor the clock (see repro.obs.audit)
+                self.obs.audit.begin_solve(req.program_id, tool,
+                                           req.turn_idx, now,
+                                           replica=self.obs_replica)
+            decision = self.policy.retention(req, tool, self.handler)
+            if sp is not None:
+                sp.set_metadata(ttl=decision.ttl, pinned=decision.ttl > 0,
+                                **_solve_inputs(decision))
+            if decision.ttl > 0:
+                n = self.blocks.pin(req.request_id, req.program_id)
+                self.pinned[req.program_id] = PinEntry(
+                    req.program_id, req.request_id, now + decision.ttl,
+                    materialized_tokens(req), now,
+                    prefix_node=req.prefix_node)  # pin inherits the radix lock
+                req.prefix_node = None
+                self.stats.pins += 1
+                self._log("pin", req.program_id, req.turn_idx,
+                          round(decision.ttl, 9))
+                return {"pinned": True, "ttl": decision.ttl, "blocks": n}
+            self._free_finished(req, now)
         return {"pinned": False, "ttl": 0.0}
 
     def _free_finished(self, req: Request, now: float,
@@ -394,7 +414,19 @@ class Scheduler:
                 if node is not None:
                     self.prefix_index.release(node)
                 return False
-        # commit
+        with span("sched.admit", program=req.program_id, turn=req.turn_idx,
+                  source=source, cached=cached,
+                  wait_s=now - req.arrival_time) as sp:
+            self._commit(req, now, source, cached, node, need)
+            if sp is not None:
+                sp.set_metadata(priced_reload_s=req.reload_seconds)
+        return True
+
+    def _commit(self, req: Request, now: float, source: str, cached: int,
+                node, need: int) -> None:
+        """Admission of ``req`` with its cached context from ``source``
+        (``cached`` tokens; ``node`` the locked radix path) and ``need``
+        blocks to allocate."""
         if source == "pin":
             self.blocks.adopt_pin(req.program_id, req.request_id)
             entry = self.pinned.pop(req.program_id)
@@ -425,7 +457,8 @@ class Scheduler:
             if self.on_reload is not None:
                 # the usable prefix (`cached`) truncates the physical
                 # restore — suffix blocks the store dropped are recomputed
-                self.on_reload(req.program_id, cached)
+                self.on_reload(req.program_id, cached,
+                               priced_s=req.reload_seconds)
         else:
             # full recompute: clear any reload debt left from an earlier
             # offload admission of this (since preempted) request
@@ -473,7 +506,6 @@ class Scheduler:
                                   req.queueing_delay)
                 drift.realize("placement_cost", req.program_id, now,
                               req.queueing_delay + req.reload_seconds)
-        return True
 
     # --------------------------------------------------- shared-prefix hooks
     def insert_prefix(self, req: Request, now: float) -> None:
@@ -524,6 +556,15 @@ class Scheduler:
         """Algorithm 1 Schedule(): admit from Q by priority until memory or
         queue is exhausted. Returns the admitted requests."""
         self.now = now
+        with span("sched.schedule", waiting=len(self.waiting)) as sp:
+            admitted = self._schedule(now, max_admits, admit_hook)
+            if sp is not None:
+                sp.set_metadata(admits=len(admitted))
+        return admitted
+
+    def _schedule(self, now: float, max_admits: int,
+                  admit_hook: Callable[[Request], None] | None
+                  ) -> list[Request]:
         self.unpin_expired(now)
         admitted: list[Request] = []
         while self.waiting and len(admitted) < max_admits:

@@ -133,6 +133,7 @@ class TTLDecision:
     prefill_reload: float
     eta: float
     t_bar: float
+    queue_eta: Optional[float] = None   # the live ETA priced, if given
 
 
 class TTLModel:
@@ -225,15 +226,16 @@ class TTLModel:
         if n_global <= cfg.cold_start_k:
             ttl = self._cold_start_ttl(G)
             return TTLDecision(min(ttl, cfg.max_ttl), 0.0, "cold_start",
-                               prefill_reload, eta, tb)
+                               prefill_reload, eta, tb, queue_eta)
 
         source = "per_tool" if (tool and n_tool > cfg.cold_start_k) else "global"
         d = self.records.durations(tool if source == "per_tool" else None)
         tau, gain = self._argmax_over_durations(d, G)
         if gain <= 0.0:
-            return TTLDecision(0.0, gain, source, prefill_reload, eta, tb)
+            return TTLDecision(0.0, gain, source, prefill_reload, eta, tb,
+                               queue_eta)
         return TTLDecision(min(tau, cfg.max_ttl), gain, source,
-                           prefill_reload, eta, tb)
+                           prefill_reload, eta, tb, queue_eta)
 
     @staticmethod
     def _argmax_over_durations(d: np.ndarray, G: float) -> tuple[float, float]:
@@ -285,7 +287,8 @@ class TTLModel:
         if self.records.count(None) <= cfg.cold_start_k:
             ttl = self._cold_start_ttl(G)
             return TTLDecision(min(ttl, cfg.max_ttl), 0.0, "cold_start",
-                               prefill_reload, self.eta_est.eta, self.t_bar.mean)
+                               prefill_reload, self.eta_est.eta,
+                               self.t_bar.mean, queue_eta)
         cands = [0.0]
         per_tool = []
         for f in tools:
@@ -304,7 +307,8 @@ class TTLModel:
         i = int(np.argmax(gains))
         if gains[i] <= 0:
             return TTLDecision(0.0, float(gains[i]), "parallel",
-                               prefill_reload, self.eta_est.eta, self.t_bar.mean)
+                               prefill_reload, self.eta_est.eta,
+                               self.t_bar.mean, queue_eta)
         return TTLDecision(min(float(taus[i]), cfg.max_ttl), float(gains[i]),
                            "parallel", prefill_reload, self.eta_est.eta,
-                           self.t_bar.mean)
+                           self.t_bar.mean, queue_eta)

@@ -12,6 +12,11 @@ All timestamps come from the virtual clock, and every event is appended
 in deterministic scheduler order, so a same-seed replay exports a
 byte-identical trace (asserted by the CI ``telemetry`` job).
 
+:func:`span` is the plane's other clock: host spans of the served path
+(``engine.*``, ``sched.*``, ``kv.*``, ``model.*``) on the profiler's
+clock, live only while a ``jax.profiler`` session records
+(:mod:`repro.obs.spans`).
+
 Wiring::
 
     tel = Telemetry()
@@ -27,10 +32,11 @@ from typing import Optional
 
 from repro.obs.audit import AuditRecord, TTLAudit
 from repro.obs.registry import MetricsRegistry
+from repro.obs.spans import span
 from repro.obs.trace import TraceRecorder
 
 __all__ = ["Telemetry", "TraceRecorder", "MetricsRegistry", "TTLAudit",
-           "AuditRecord"]
+           "AuditRecord", "span"]
 
 # decision kinds that also mark the program's own async track
 _PROGRAM_MARKS = {"demote": "demoted", "evict": "evicted",
@@ -98,7 +104,19 @@ class Telemetry:
             ("replica",))
         self.reload_seconds = m.histogram(
             "continuum_reload_seconds",
-            "Offload-tier reload latency paid at admission", ("replica",))
+            "Offload-tier reload latency the scheduler priced at admission "
+            "(the measured move is continuum_tier_move_seconds)",
+            ("replica",))
+        self.tier_bytes = m.counter(
+            "continuum_tier_bytes_total",
+            "KV bytes the paged backend moved between HBM and host memory "
+            "(direction: d2h = stage-out, h2d = restore)",
+            ("replica", "direction"))
+        self.tier_move_seconds = m.histogram(
+            "continuum_tier_move_seconds",
+            "Host wall seconds of one tier move of the paged backend "
+            "(direction: d2h = stage-out, h2d = restore)",
+            ("replica", "direction"))
         self.queue_eta = m.gauge(
             "continuum_queue_eta_seconds",
             "Live queueing-delay ETA a new arrival would see", ("replica",))
@@ -154,7 +172,6 @@ class Telemetry:
         if runtime is not None:
             runtime.obs = self
             runtime.obs_replica = r
-            runtime.obs_clock = lambda: engine.clock
         self._engines.append(engine)
         if self.drift is not None:
             self._wire_drift_engine(engine)

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.obs.spans import span
 from repro.serving.paged_runtime import PAGED_FAMILIES, PagedKVRuntime, _pow2
 from repro.serving.prefix import (PrefixConfig, RadixPrefixIndex,
                                   request_block_hashes)
@@ -121,6 +122,12 @@ def kv_pool_budget(cfg: ModelConfig, *, limit: float, held_bytes: float,
     return max(pool - extra_pages * page_size * kv_per_pos, 0.0)
 
 
+def _padded(nbytes: int, pages: int) -> int:
+    """Bytes of ``pages`` pages of ``nbytes`` at the power-of-two width
+    a tier move stages them at."""
+    return nbytes // pages * _pow2(pages)
+
+
 class JaxModelBackend:
     """Real generation; per-program KV in a PagedKVRuntime's physical
     pages (so a TTL hit genuinely reuses the computed cache, an eviction
@@ -161,6 +168,13 @@ class JaxModelBackend:
         self.decode_tokens_computed = 0
         self.demotions = 0
         self.restores = 0
+        # tier moves: KV bytes of the programs' real pages (k + v) and the
+        # host wall of each move, stage-out through evict, restore through
+        # the scatter dispatch (``kv.stage_out``/``kv.restore`` spans)
+        self.stage_out_bytes = 0
+        self.restore_bytes = 0
+        self.stage_out_seconds = 0.0
+        self.restore_seconds = 0.0
         self.shortfall_tokens = 0       # defensive recompute (cache lost)
         # differential harness: verify every restore round-trips bit-exact
         self.verify_staging = False
@@ -271,17 +285,44 @@ class JaxModelBackend:
         e = rt.programs.get(program_id)
         if e is None or e.length == 0:
             return
-        self.host_caches[program_id] = rt.stage_out(program_id)
-        rt.evict(program_id, force=True)
+        t0 = time.perf_counter()
+        with span("kv.stage_out", program=program_id,
+                  pages=len(e.pages)) as sp:
+            k, v, n = rt.stage_out(program_id)
+            self.host_caches[program_id] = (k, v, n)
+            rt.evict(program_id, force=True)
+            nbytes = k.nbytes + v.nbytes
+            if sp is not None:
+                sp.set_metadata(bytes=nbytes, padded_bytes=_padded(
+                    nbytes, k.shape[1]))
+        self._note_move("d2h", nbytes, time.perf_counter() - t0)
         self.demotions += 1
 
+    def _note_move(self, direction: str, nbytes: int, seconds: float) -> None:
+        """Tier-move counters, and the registry's when telemetry is
+        attached (``d2h`` = stage-out, ``h2d`` = restore)."""
+        if direction == "d2h":
+            self.stage_out_bytes += nbytes
+            self.stage_out_seconds += seconds
+        else:
+            self.restore_bytes += nbytes
+            self.restore_seconds += seconds
+        obs = self.runtime.obs
+        if obs is not None:
+            key = (self.runtime.obs_replica, direction)
+            obs.tier_bytes.inc(nbytes, key)
+            obs.tier_move_seconds.observe(seconds, key)
+
     def restore_program(self, program_id: str,
-                        tokens: Optional[int] = None) -> None:
+                        tokens: Optional[int] = None,
+                        priced_s: float = 0.0) -> None:
         """Offload-tier reload: scatter the staged host copy back into
         freshly allocated physical pages. ``tokens`` (the store entry's
         usable prefix — it shrinks when suffix blocks were dropped under
         tier pressure) truncates the restore; the engine recomputes the
-        rest."""
+        rest. ``priced_s`` is the reload time the scheduler priced for
+        it, recorded beside the measured move in the ``kv.restore``
+        span."""
         entry = self.host_caches.pop(program_id, None)
         if entry is None:
             return                       # lost copy: engine recomputes
@@ -293,7 +334,13 @@ class JaxModelBackend:
         ps = self.runtime.page_size
         pages = math.ceil(n / ps)
         k, v = k[:, :pages], v[:, :pages]
-        ids = self.runtime.restore(program_id, k, v, n)
+        nbytes = k.nbytes + v.nbytes
+        t0 = time.perf_counter()
+        with span("kv.restore", program=program_id, pages=pages,
+                  bytes=nbytes, padded_bytes=_padded(nbytes, pages),
+                  priced_s=priced_s):
+            ids = self.runtime.restore(program_id, k, v, n)
+        self._note_move("h2d", nbytes, time.perf_counter() - t0)
         if self.verify_staging:          # differential harness: bit-exact?
             back_k, back_v = self.runtime.read_pages(ids)
             ok = bool(np.array_equal(back_k, k)) and \
@@ -379,8 +426,9 @@ class JaxModelBackend:
             self.decode_tokens_computed += len(decode_pids)
         # the step ends when the device is done: the pools are the last
         # thing every step writes, the next tokens the last it reads
-        jax.block_until_ready((rt.k_pages, rt.v_pages,
-                               [rt._last[p] for p in decode_pids]))
+        with span("model.sync"):
+            jax.block_until_ready((rt.k_pages, rt.v_pages,
+                                   [rt._last[p] for p in decode_pids]))
         dt = time.perf_counter() - t0 - (compile_stats()["seconds"] - c0)
         dt = max(dt, 1e-6)
         self.step_seconds.append(dt)

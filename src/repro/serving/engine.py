@@ -34,6 +34,7 @@ from repro.core.scheduler import Scheduler, materialized_tokens
 from repro.core.tool_handler import ToolCallHandler
 from repro.core.ttl import TTLConfig, TTLModel
 from repro.core.types import ProgramStats, Request, RequestState
+from repro.obs.spans import span
 from repro.serving.blocks import BlockConfig, BlockManager
 from repro.serving.offload import OffloadConfig, OffloadManager
 from repro.serving.prefix import (PrefixConfig, RadixPrefixIndex,
@@ -95,6 +96,9 @@ class StepEvents:
     tool_started: list = dataclasses.field(default_factory=list)  # (req, tool)
     admitted: list = dataclasses.field(default_factory=list)
     idle: bool = False
+    running: int = 0            # requests in the running set at execute
+    prefill_tokens: int = 0     # prefill tokens this step computed
+    decode_rows: int = 0        # decode rows this step ran
     # scheduling decisions made during this step, in order (admit source,
     # pin/unpin, demote/evict, reload, preempt) — the differential replay
     # harness compares these streams between logical and physical runs
@@ -365,6 +369,19 @@ class Engine:
 
     # ----------------------------------------------------------------- step
     def step(self, now: float) -> StepEvents:
+        """One engine iteration at ``now``. While a profiler records, an
+        ``engine.step`` span covers it, its phases ``engine.admit``,
+        ``engine.compose``, ``engine.execute`` and ``engine.advance``."""
+        with span("engine.step", step=self.steps) as sp:
+            ev = self._step(now)
+            if sp is not None:
+                sp.set_metadata(running=ev.running,
+                                admitted=len(ev.admitted),
+                                prefill_tokens=ev.prefill_tokens,
+                                decode_rows=ev.decode_rows)
+        return ev
+
+    def _step(self, now: float) -> StepEvents:
         ev = StepEvents()
         self.clock = now            # anchors TransferEngine-based pricing
         self.scheduler.decision_sink = ev.decisions
@@ -378,79 +395,86 @@ class Engine:
         drift = self.obs.drift if self.obs is not None else None
         est_step = self.est_step_seconds() if drift is not None else 0.0
         # 1. admission (Algorithm 1 Schedule())
-        cap = self.ecfg.max_batch - len(self.running)
-        if cap > 0:
-            admitted = self.scheduler.schedule(now, max_admits=cap)
-            for r in admitted:
-                r.prefill_pos = r.cached_prefix
-                self.running.append(r)
-                if self.obs is not None:
-                    # fully-cached prompts (pin adoption) skip prefill
-                    self.obs.program_phase(
-                        r.program_id,
-                        "decode" if r.done_prefill() else "prefill", now,
-                        args={"turn": r.turn_idx,
-                              "cached": r.cached_prefix})
-            ev.admitted = admitted
+        with span("engine.admit"):
+            cap = self.ecfg.max_batch - len(self.running)
+            if cap > 0:
+                admitted = self.scheduler.schedule(now, max_admits=cap)
+                for r in admitted:
+                    r.prefill_pos = r.cached_prefix
+                    self.running.append(r)
+                    if self.obs is not None:
+                        # fully-cached prompts (pin adoption) skip prefill
+                        self.obs.program_phase(
+                            r.program_id,
+                            "decode" if r.done_prefill() else "prefill", now,
+                            args={"turn": r.turn_idx,
+                                  "cached": r.cached_prefix})
+                ev.admitted = admitted
 
         if not self.running:
             ev.idle = True
             return self._finish_step(ev, now)
 
         # 2. compose the batch: chunked prefill + decode
-        budget = self.ecfg.chunk_size
-        prefill_work: list[PrefillWork] = []
-        for r in self.running:
-            if budget <= 0:
-                break
-            if not r.done_prefill():
-                chunk = min(budget, r.prompt_len - r.prefill_pos)
-                prefill_work.append(PrefillWork(r, chunk, r.prefill_pos))
-                budget -= chunk
+        with span("engine.compose"):
+            budget = self.ecfg.chunk_size
+            prefill_work: list[PrefillWork] = []
+            for r in self.running:
+                if budget <= 0:
+                    break
+                if not r.done_prefill():
+                    chunk = min(budget, r.prompt_len - r.prefill_pos)
+                    prefill_work.append(PrefillWork(r, chunk, r.prefill_pos))
+                    budget -= chunk
 
-        decode_reqs = [r for r in self.running
-                       if r.done_prefill() and not r.done()]
+            decode_reqs = [r for r in self.running
+                           if r.done_prefill() and not r.done()]
 
-        # 3. decode block growth (+ preemption on OOM; unreferenced shared
-        #    prefix cache is reclaimed first — cheaper than preempting)
-        for r in list(decode_reqs):
-            if r not in decode_reqs:    # preempted as an earlier r's victim
-                continue
-            pos = r.prompt_len + r.generated
-            if pos % self.ecfg.block_size == 0 and self.profile.kv_bytes_per_token > 0:
-                while not self.blocks.extend(r.request_id, 1):
-                    if self.scheduler.prefix_reclaim(1) > 0:
-                        continue
-                    victim = self._pick_preemption_victim(exclude=r)
-                    if victim is None:
-                        break
-                    self._preempt(victim, now)
-                    if victim in decode_reqs:
-                        decode_reqs.remove(victim)
-                    # a mid-prefill victim must leave the batch too: its
-                    # blocks are freed and its pages staged out/evicted —
-                    # executing its stale chunk would advance a PREEMPTED
-                    # request and re-create the entry the backend dropped
-                    prefill_work = [w for w in prefill_work
-                                    if w.req is not victim]
+            # 3. decode block growth (+ preemption on OOM; unreferenced shared
+            #    prefix cache is reclaimed first — cheaper than preempting)
+            for r in list(decode_reqs):
+                if r not in decode_reqs:    # preempted as an earlier r's victim
+                    continue
+                pos = r.prompt_len + r.generated
+                if pos % self.ecfg.block_size == 0 and self.profile.kv_bytes_per_token > 0:
+                    while not self.blocks.extend(r.request_id, 1):
+                        if self.scheduler.prefix_reclaim(1) > 0:
+                            continue
+                        victim = self._pick_preemption_victim(exclude=r)
+                        if victim is None:
+                            break
+                        self._preempt(victim, now)
+                        if victim in decode_reqs:
+                            decode_reqs.remove(victim)
+                        # a mid-prefill victim must leave the batch too: its
+                        # blocks are freed and its pages staged out/evicted —
+                        # executing its stale chunk would advance a PREEMPTED
+                        # request and re-create the entry the backend dropped
+                        prefill_work = [w for w in prefill_work
+                                        if w.req is not victim]
 
-        # Reload stalls gate the whole step — every co-scheduled request
-        # pays the slowest participant's reload (the router prices this
-        # collateral). Charged on the FIRST step the request participates
-        # in, prefill chunk or decode alike: a fully-cached admission (pin
-        # adoption after a DRAM restore) goes straight to decode and must
-        # still pay its stall. Cleared unconditionally so a stale value
-        # never survives to be re-charged on a later turn.
-        reload_penalty = 0.0
-        for r in [w.req for w in prefill_work] + decode_reqs:
-            if r.reload_seconds > 0:
-                reload_penalty = max(reload_penalty, r.reload_seconds)
-                r.reload_seconds = 0.0
+            # Reload stalls gate the whole step — every co-scheduled request
+            # pays the slowest participant's reload (the router prices this
+            # collateral). Charged on the FIRST step the request participates
+            # in, prefill chunk or decode alike: a fully-cached admission (pin
+            # adoption after a DRAM restore) goes straight to decode and must
+            # still pay its stall. Cleared unconditionally so a stale value
+            # never survives to be re-charged on a later turn.
+            reload_penalty = 0.0
+            for r in [w.req for w in prefill_work] + decode_reqs:
+                if r.reload_seconds > 0:
+                    reload_penalty = max(reload_penalty, r.reload_seconds)
+                    r.reload_seconds = 0.0
+
+        ev.running = len(self.running)
+        ev.prefill_tokens = sum(w.chunk for w in prefill_work)
+        ev.decode_rows = len(decode_reqs)
 
         # 4. execute. Tier reloads are DMA transfers on their own channels,
         # so they overlap the step's compute; only the slower of the two
         # paces the step (LMCache-style async offload, paper §5.2).
-        exec_s = self.backend.execute(prefill_work, decode_reqs)
+        with span("engine.execute"):
+            exec_s = self.backend.execute(prefill_work, decode_reqs)
         stall = max(0.0, reload_penalty - exec_s)
         dur = exec_s + stall + self.ecfg.scheduler_overhead_s
         ev.duration = dur
@@ -458,7 +482,7 @@ class Engine:
         self.steps += 1
         if self.obs is not None:
             rid = self.engine_id
-            p_tok = sum(w.chunk for w in prefill_work)
+            p_tok = ev.prefill_tokens
             args = {"prefill_tokens": p_tok, "decode": len(decode_reqs),
                     "running": len(self.running)}
             if stall > 0.0:
@@ -491,59 +515,60 @@ class Engine:
                 self.obs.tokens.inc(len(decode_reqs), (rid, "decode"))
 
         # 5. advance state
-        total_tok = sum(w.chunk for w in prefill_work) + len(decode_reqs) or 1
-        end = now + dur
-        for w in prefill_work:
-            w.req.prefill_pos += w.chunk
-            self.tokens_prefilled += w.chunk
-            if w.req.done_prefill():
-                w.req.generated = max(w.req.generated, 1)  # prefill emits tok 1
+        with span("engine.advance"):
+            total_tok = sum(w.chunk for w in prefill_work) + len(decode_reqs) or 1
+            end = now + dur
+            for w in prefill_work:
+                w.req.prefill_pos += w.chunk
+                self.tokens_prefilled += w.chunk
+                if w.req.done_prefill():
+                    w.req.generated = max(w.req.generated, 1)  # prefill emits tok 1
+                    self.tokens_decoded += 1
+                    self._note_first_token(w.req, end)
+                    # publish the finished prompt into the shared-prefix index
+                    self.scheduler.insert_prefix(w.req, end)
+                    if self.obs is not None:
+                        self.obs.program_phase(w.req.program_id, "decode", end)
+                self.scheduler.note_service(
+                    w.req.program_id, dur * w.chunk / total_tok)
+            for r in decode_reqs:
+                r.generated += 1
                 self.tokens_decoded += 1
-                self._note_first_token(w.req, end)
-                # publish the finished prompt into the shared-prefix index
-                self.scheduler.insert_prefix(w.req, end)
-                if self.obs is not None:
-                    self.obs.program_phase(w.req.program_id, "decode", end)
-            self.scheduler.note_service(
-                w.req.program_id, dur * w.chunk / total_tok)
-        for r in decode_reqs:
-            r.generated += 1
-            self.tokens_decoded += 1
-            self._note_first_token(r, end)   # fully-cached prompts skip prefill
-            self.scheduler.note_service(r.program_id, dur * 1 / total_tok)
+                self._note_first_token(r, end)   # fully-cached prompts skip prefill
+                self.scheduler.note_service(r.program_id, dur * 1 / total_tok)
 
-        # 6. completions
-        for r in list(self.running):
-            if r.done_prefill() and r.done():
-                self.running.remove(r)
-                info = self.scheduler.on_request_finish(r, end)
-                ev.finished.append(r)
-                ps = self.programs[r.program_id]
-                ps.total_queueing += r.queueing_delay
-                if r.served_from_shared:
-                    ps.prefix_hits += 1
-                    ps.prefix_hit_tokens += r.cached_prefix
-                if r.served_from_pin:
-                    ps.ttl_hits += 1
-                elif r.turn_idx > 0:
-                    ps.ttl_misses += 1
-                if r.is_last_turn or r.tool is None:
-                    ps.finish_time = end
-                    if self.obs is not None:
-                        self.obs.program_end(r.program_id, end)
-                        self.obs.programs_finished.inc(1.0, (self.engine_id,))
-                        # tenant identity rides on the shared-prefix id
-                        # (the skewed workload encodes tenants there);
-                        # feeds the JCT histogram + per-tenant SLO burn
-                        self.obs.note_jct(self.engine_id,
-                                          r.shared_prefix_id or "default",
-                                          ps.jct, end)
-                else:
-                    ev.tool_started.append((r, r.tool))
-                    ps.total_tool_time += r.tool_duration
-                    if self.obs is not None:
-                        self.obs.program_phase(r.program_id, "tool_pause",
-                                               end, args={"tool": r.tool})
+            # 6. completions
+            for r in list(self.running):
+                if r.done_prefill() and r.done():
+                    self.running.remove(r)
+                    info = self.scheduler.on_request_finish(r, end)
+                    ev.finished.append(r)
+                    ps = self.programs[r.program_id]
+                    ps.total_queueing += r.queueing_delay
+                    if r.served_from_shared:
+                        ps.prefix_hits += 1
+                        ps.prefix_hit_tokens += r.cached_prefix
+                    if r.served_from_pin:
+                        ps.ttl_hits += 1
+                    elif r.turn_idx > 0:
+                        ps.ttl_misses += 1
+                    if r.is_last_turn or r.tool is None:
+                        ps.finish_time = end
+                        if self.obs is not None:
+                            self.obs.program_end(r.program_id, end)
+                            self.obs.programs_finished.inc(1.0, (self.engine_id,))
+                            # tenant identity rides on the shared-prefix id
+                            # (the skewed workload encodes tenants there);
+                            # feeds the JCT histogram + per-tenant SLO burn
+                            self.obs.note_jct(self.engine_id,
+                                              r.shared_prefix_id or "default",
+                                              ps.jct, end)
+                    else:
+                        ev.tool_started.append((r, r.tool))
+                        ps.total_tool_time += r.tool_duration
+                        if self.obs is not None:
+                            self.obs.program_phase(r.program_id, "tool_pause",
+                                                   end, args={"tool": r.tool})
         return self._finish_step(ev, now)
 
     def _finish_step(self, ev: StepEvents, now: float) -> StepEvents:

@@ -42,6 +42,7 @@ from repro.models import attention as attn_mod
 from repro.models.common import cast_params, rms_norm
 from repro.models.mlp import mlp_apply
 from repro.models.transformer import Model
+from repro.obs.spans import span
 
 
 #: families whose per-token KV lives in uniform pages (the runtime's —
@@ -131,18 +132,11 @@ class PagedKVRuntime:
         # verified bit-exact (copied page == source page) and recorded
         self.verify_copies = False
         self.copy_checks: list[bool] = []
-        # telemetry (repro.obs): COW splits / stage-out / restore land on
-        # the owning replica's lane; obs_clock supplies the virtual time
-        # (the runtime itself is clockless)
+        # telemetry (repro.obs): COW splits count on the owning
+        # replica's registry; the owning backend reports its tier moves
+        # through the same handle
         self.obs = None
         self.obs_replica = ""
-        self.obs_clock = None  # type: Optional[callable]
-
-    def _obs_event(self, name: str, program_id: str, args: dict) -> None:
-        if self.obs is not None:
-            now = self.obs_clock() if self.obs_clock is not None else 0.0
-            self.obs.tier_event(self.obs_replica, name, program_id, now,
-                                args)
 
     # ------------------------------------------------------------- alloc
     def _alloc_page(self) -> int:
@@ -192,13 +186,14 @@ class PagedKVRuntime:
         pi = e.pages[idx]
         if self.refs.get(pi, 1) == 1:
             return pi
-        new = self._alloc_page()
-        src = jnp.asarray([pi], jnp.int32)
-        dst = jnp.asarray([new], jnp.int32)
-        self.k_pages = copy_pages(self.k_pages, src, dst,
-                                  interpret=self.interpret)
-        self.v_pages = copy_pages(self.v_pages, src, dst,
-                                  interpret=self.interpret)
+        with span("kv.cow_split", src_page=pi):
+            new = self._alloc_page()
+            src = jnp.asarray([pi], jnp.int32)
+            dst = jnp.asarray([new], jnp.int32)
+            self.k_pages = copy_pages(self.k_pages, src, dst,
+                                      interpret=self.interpret)
+            self.v_pages = copy_pages(self.v_pages, src, dst,
+                                      interpret=self.interpret)
         if self.verify_copies:          # differential harness: bit-exact?
             ok = bool(jnp.array_equal(self.k_pages[:, new],
                                       self.k_pages[:, pi])) and \
@@ -210,8 +205,6 @@ class PagedKVRuntime:
         self.cow_splits += 1
         if self.obs is not None:
             self.obs.cow_splits.inc(1.0, (self.obs_replica,))
-            self._obs_event("cow_split", "", {"src_page": int(pi),
-                                              "dst_page": int(new)})
         return new
 
     def evict(self, program_id: str, force: bool = False) -> bool:
@@ -320,12 +313,18 @@ class PagedKVRuntime:
     def read_pages(self, ids: list[int]) -> tuple[np.ndarray, np.ndarray]:
         """Batch-gather pages ``ids`` into contiguous (L, n, page, KV, Dh)
         host buffers: one gather kernel per pool, at a power-of-two
-        width so tier moves compile O(log) shapes, then one D2H copy."""
+        width so tier moves compile O(log) shapes, then one D2H copy.
+        Spans: ``kv.gather`` (the two dispatches; their device work is
+        the trace's ``jit_gather_pages``), ``kv.d2h`` (the blocking
+        copies)."""
         (pad,) = _pad_ids(list(ids), _pow2(len(ids)))
-        pad = jnp.asarray(pad)
-        k = gather_pages(self.k_pages, pad, interpret=self.interpret)
-        v = gather_pages(self.v_pages, pad, interpret=self.interpret)
-        return np.asarray(k)[:, :len(ids)], np.asarray(v)[:, :len(ids)]
+        with span("kv.gather"):
+            pad = jnp.asarray(pad)
+            k = gather_pages(self.k_pages, pad, interpret=self.interpret)
+            v = gather_pages(self.v_pages, pad, interpret=self.interpret)
+        with span("kv.d2h"):
+            k, v = np.asarray(k), np.asarray(v)
+        return k[:, :len(ids)], v[:, :len(ids)]
 
     def stage_out(self, program_id: str
                   ) -> tuple[np.ndarray, np.ndarray, int]:
@@ -333,15 +332,16 @@ class PagedKVRuntime:
         buffers (:meth:`read_pages`) — the unit a tier move DMAs to host
         DRAM in one transfer — and its length."""
         e = self.programs[program_id]
-        self._obs_event("stage_out", program_id, {"pages": len(e.pages),
-                                                  "length": e.length})
         return (*self.read_pages(e.pages), e.length)
 
     def restore(self, program_id: str, k_staging, v_staging,
                 length: int) -> list[int]:
         """Scatter reloaded contiguous (host) staging buffers into freshly
         allocated physical pages (the H2D leg of a promotion), padded to a
-        power-of-two width by repeating the last page."""
+        power-of-two width by repeating the last page. Spans, once per
+        pool: ``kv.restore_pad`` (the padded host copy), ``kv.h2d`` (its
+        copy to the device), ``kv.scatter`` (the dispatch; its device work
+        is the trace's ``jit_scatter_pages``)."""
         stale = self.programs.pop(program_id, None)
         if stale is not None:           # defensive: never leak pages
             for pi in stale.pages:
@@ -357,16 +357,23 @@ class PagedKVRuntime:
             raise
         (ids,) = _pad_ids(pages, _pow2(n))
         take = np.minimum(np.arange(len(ids)), n - 1)
-        self.k_pages = scatter_pages(
-            self.k_pages, jnp.asarray(np.asarray(k_staging)[:, take]),
-            jnp.asarray(ids), interpret=self.interpret)
-        self.v_pages = scatter_pages(
-            self.v_pages, jnp.asarray(np.asarray(v_staging)[:, take]),
-            jnp.asarray(ids), interpret=self.interpret)
+        ids = jnp.asarray(ids)
+        # pool by pool: k's copy to the device overlaps v's padding
+        self.k_pages = self._restore_pool(self.k_pages, k_staging, take, ids)
+        self.v_pages = self._restore_pool(self.v_pages, v_staging, take, ids)
         self.programs[program_id] = ProgramEntry(pages, length)
-        self._obs_event("restore", program_id, {"pages": len(pages),
-                                                "length": length})
         return pages
+
+    def _restore_pool(self, pool, staging, take, ids):
+        """One pool's leg of :meth:`restore`: the staging buffer padded on
+        the host by ``take``, copied to the device, scattered into
+        ``pool`` at ``ids``."""
+        with span("kv.restore_pad"):
+            padded = np.asarray(staging)[:, take]
+        with span("kv.h2d"):
+            padded = jnp.asarray(padded)
+        with span("kv.scatter"):
+            return scatter_pages(pool, padded, ids, interpret=self.interpret)
 
     # ----------------------------------------------------------- prefill
     def prefill(self, params, program_id: str, tokens: jax.Array,
@@ -403,22 +410,24 @@ class PagedKVRuntime:
                                           max_len=max_len)
                     i += 1 << bit
             return logits
-        self._ensure_capacity(e, start + S)       # pages for REAL tokens only
-        T = _pow2(max(len(e.pages) * self.page_size, start + Sp))
-        tokens = np.pad(tokens, (0, Sp - S))
-        cache = self.model.init_cache(1, T)
-        if start:
-            # re-materialize existing pages into the contiguous scratch
-            cache = self._gather_into(cache, e)
-        logits, cache = self._forward(
-            params, tokens=tokens.reshape(1, Sp), cache=cache,
-            cache_len=jnp.asarray(start, jnp.int32),
-            mode="extend" if start else "prefill",
-            logits_at=jnp.asarray(S - 1, jnp.int32))
-        self._scatter_from(cache, e, start, S)
-        e.length = start + S
-        self._last[program_id] = jnp.argmax(logits[0, 0]).astype(jnp.int32)
-        return logits[0, 0]
+        with span("model.prefill", program=program_id, start=start,
+                  tokens=S, pad_to=Sp):
+            self._ensure_capacity(e, start + S)       # pages for REAL tokens only
+            T = _pow2(max(len(e.pages) * self.page_size, start + Sp))
+            tokens = np.pad(tokens, (0, Sp - S))
+            cache = self.model.init_cache(1, T)
+            if start:
+                # re-materialize existing pages into the contiguous scratch
+                cache = self._gather_into(cache, e)
+            logits, cache = self._forward(
+                params, tokens=tokens.reshape(1, Sp), cache=cache,
+                cache_len=jnp.asarray(start, jnp.int32),
+                mode="extend" if start else "prefill",
+                logits_at=jnp.asarray(S - 1, jnp.int32))
+            self._scatter_from(cache, e, start, S)
+            e.length = start + S
+            self._last[program_id] = jnp.argmax(logits[0, 0]).astype(jnp.int32)
+            return logits[0, 0]
 
     def _scatter_from(self, cache, e: ProgramEntry, start: int, count: int):
         """Copy cache[k/v][:, 0, start:start+count] into physical pages."""
@@ -527,40 +536,43 @@ class PagedKVRuntime:
             return []
         assert len(set(program_ids)) == len(program_ids), \
             "duplicate program ids in one decode batch"
-        entries = [self.programs[pid] for pid in program_ids]
-        ps = self.page_size
-        for e in entries:
-            self._ensure_capacity(e, e.length + 1)
-            # every append page must be exclusive BEFORE the tables are
-            # built: a COW split mid-batch would leave some row's table
-            # pointing at the stale shared page
-            self._writable_page(e, e.length // ps)
-        B = len(entries)
-        # ragged tables, padded to a pow2 width with the valid sentinel
-        # page 0 (the kernel's DMA index map reads EVERY slot — see
-        # kernels/decode_attention: garbage padding is an OOB fetch on
-        # hardware); pow2 bucketing bounds XLA retraces to O(log pages)
-        max_pages = max(len(e.pages) for e in entries)
-        n_tab = 1 << max(0, max_pages - 1).bit_length()
-        tables = np.zeros((B, n_tab), np.int32)
-        for i, e in enumerate(entries):
-            tables[i, :len(e.pages)] = e.pages
-        lens = np.asarray([e.length for e in entries], np.int32)
-        app_pages = np.asarray([e.pages[e.length // ps] for e in entries],
-                               np.int32)
-        app_offs = np.asarray([e.length % ps for e in entries], np.int32)
-        assert len(set(app_pages.tolist())) == B, \
-            "append pages must be pairwise distinct (COW resolved above)"
-        toks = jnp.stack([self._last_token(params, pid)
-                          for pid in program_ids])
-        logits, nxt, self.k_pages, self.v_pages = self._decode_step(
-            params, self.k_pages, self.v_pages, toks,
-            jnp.asarray(tables), jnp.asarray(lens),
-            jnp.asarray(app_pages), jnp.asarray(app_offs))
-        for i, pid in enumerate(program_ids):
-            self.programs[pid].length += 1
-            self._last[pid] = nxt[i]
-        return [logits[i] for i in range(B)]
+        with span("model.decode", rows=len(program_ids)) as sp:
+            entries = [self.programs[pid] for pid in program_ids]
+            ps = self.page_size
+            for e in entries:
+                self._ensure_capacity(e, e.length + 1)
+                # every append page must be exclusive BEFORE the tables are
+                # built: a COW split mid-batch would leave some row's table
+                # pointing at the stale shared page
+                self._writable_page(e, e.length // ps)
+            B = len(entries)
+            # ragged tables, padded to a pow2 width with the valid sentinel
+            # page 0 (the kernel's DMA index map reads EVERY slot — see
+            # kernels/decode_attention: garbage padding is an OOB fetch on
+            # hardware); pow2 bucketing bounds XLA retraces to O(log pages)
+            max_pages = max(len(e.pages) for e in entries)
+            n_tab = 1 << max(0, max_pages - 1).bit_length()
+            if sp is not None:
+                sp.set_metadata(n_tab=n_tab)
+            tables = np.zeros((B, n_tab), np.int32)
+            for i, e in enumerate(entries):
+                tables[i, :len(e.pages)] = e.pages
+            lens = np.asarray([e.length for e in entries], np.int32)
+            app_pages = np.asarray([e.pages[e.length // ps] for e in entries],
+                                   np.int32)
+            app_offs = np.asarray([e.length % ps for e in entries], np.int32)
+            assert len(set(app_pages.tolist())) == B, \
+                "append pages must be pairwise distinct (COW resolved above)"
+            toks = jnp.stack([self._last_token(params, pid)
+                              for pid in program_ids])
+            logits, nxt, self.k_pages, self.v_pages = self._decode_step(
+                params, self.k_pages, self.v_pages, toks,
+                jnp.asarray(tables), jnp.asarray(lens),
+                jnp.asarray(app_pages), jnp.asarray(app_offs))
+            for i, pid in enumerate(program_ids):
+                self.programs[pid].length += 1
+                self._last[pid] = nxt[i]
+            return [logits[i] for i in range(B)]
 
     def decode(self, params, program_id: str) -> jax.Array:
         """One decode step for the program's last token, attention served by
