@@ -158,7 +158,7 @@ class JaxModelBackend:
         self._rng = rng
         self._streams: dict[str, np.ndarray] = {}  # stream -> token ids
         # staged-out host copies: program_id -> (np k, np v, tokens); the
-        # buffers are the page_copy staging layout (L, pages, page, KV, Dh)
+        # buffers are the page_copy gather's (L, pow2(pages), page, KV, Dh)
         self.host_caches: dict[str, tuple] = {}
         # page-stamped radix mirror of the scheduler's accounting index
         # (enable_prefix_sharing); None = no cross-program sharing
@@ -168,6 +168,9 @@ class JaxModelBackend:
         self.decode_tokens_computed = 0
         self.demotions = 0
         self.restores = 0
+        # restores that kept fewer pages than were staged (the store's
+        # usable prefix shrank): the device-side re-pad drops the rest
+        self.restores_truncated = 0
         # tier moves: KV bytes of the programs' real pages (k + v) and the
         # host wall of each move, stage-out through evict, restore through
         # the scatter dispatch (``kv.stage_out``/``kv.restore`` spans)
@@ -291,10 +294,10 @@ class JaxModelBackend:
             k, v, n = rt.stage_out(program_id)
             self.host_caches[program_id] = (k, v, n)
             rt.evict(program_id, force=True)
-            nbytes = k.nbytes + v.nbytes
+            nbytes = (k.nbytes + v.nbytes) // k.shape[1] * len(e.pages)
             if sp is not None:
                 sp.set_metadata(bytes=nbytes, padded_bytes=_padded(
-                    nbytes, k.shape[1]))
+                    nbytes, len(e.pages)))
         self._note_move("d2h", nbytes, time.perf_counter() - t0)
         self.demotions += 1
 
@@ -326,25 +329,26 @@ class JaxModelBackend:
         entry = self.host_caches.pop(program_id, None)
         if entry is None:
             return                       # lost copy: engine recomputes
-        k, v, n = entry
-        if tokens is not None:
-            n = min(n, int(tokens))
+        k, v, staged = entry
+        n = staged if tokens is None else min(staged, int(tokens))
         if n <= 0:
             return
         ps = self.runtime.page_size
         pages = math.ceil(n / ps)
-        k, v = k[:, :pages], v[:, :pages]
-        nbytes = k.nbytes + v.nbytes
+        staged_pages = math.ceil(staged / ps)
+        nbytes = (k.nbytes + v.nbytes) // k.shape[1] * pages
         t0 = time.perf_counter()
         with span("kv.restore", program=program_id, pages=pages,
-                  bytes=nbytes, padded_bytes=_padded(nbytes, pages),
-                  priced_s=priced_s):
+                  staged_pages=staged_pages, bytes=nbytes,
+                  padded_bytes=_padded(nbytes, pages), priced_s=priced_s):
             ids = self.runtime.restore(program_id, k, v, n)
         self._note_move("h2d", nbytes, time.perf_counter() - t0)
+        if pages < staged_pages:
+            self.restores_truncated += 1
         if self.verify_staging:          # differential harness: bit-exact?
             back_k, back_v = self.runtime.read_pages(ids)
-            ok = bool(np.array_equal(back_k, k)) and \
-                bool(np.array_equal(back_v, v))
+            ok = bool(np.array_equal(back_k[:, :pages], k[:, :pages])) \
+                and bool(np.array_equal(back_v[:, :pages], v[:, :pages]))
             self.staging_checks.append((program_id, ok))
         self.restores += 1
 

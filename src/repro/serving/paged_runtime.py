@@ -15,7 +15,8 @@ page triggers a copy-on-write split through the ``page_copy`` Pallas
 kernel — the prefix is shared in HBM for real, not just in accounting.
 ``stage_out``/``restore`` batch-gather scattered pages into contiguous
 staging buffers (one bulk DMA) for tier moves through the
-:mod:`repro.serving.kvstore` store.
+:mod:`repro.serving.kvstore` store, and scatter them back from those
+same buffers.
 
 Works for the uniform-attention families (dense/moe/audio/vlm). The
 engine-level BlockManager does the accounting; this runtime holds the
@@ -92,6 +93,21 @@ def _scatter_span(k_pages, v_pages, cache_k, cache_v, ids, blocks, lo, hi, *,
         return scatter_pages(pages, jnp.where(live, new, old), ids,
                              interpret=interpret)
     return one(k_pages, cache_k), one(v_pages, cache_v)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _restore_pages(k_pages, v_pages, k_staged, v_staged, ids, n, *,
+                   interpret):
+    """Staged (L, W, page, KV, Dh) buffers -> physical pages ``ids`` (W,),
+    padded by repeating the last of the n kept pages: slot i scatters
+    staged page min(i, n - 1), so a padded slot redoes the last kept
+    page's copy and never carries a page past the kept prefix."""
+    take = jnp.minimum(jnp.arange(ids.shape[0]), n - 1)
+
+    def one(pages, staged):
+        return scatter_pages(pages, staged[:, take], ids,
+                             interpret=interpret)
+    return one(k_pages, k_staged), one(v_pages, v_staged)
 
 
 @dataclasses.dataclass
@@ -311,42 +327,46 @@ class PagedKVRuntime:
 
     # ------------------------------------------------------- tier staging
     def read_pages(self, ids: list[int]) -> tuple[np.ndarray, np.ndarray]:
-        """Batch-gather pages ``ids`` into contiguous (L, n, page, KV, Dh)
-        host buffers: one gather kernel per pool, at a power-of-two
-        width so tier moves compile O(log) shapes, then one D2H copy.
-        Spans: ``kv.gather`` (the two dispatches; their device work is
-        the trace's ``jit_gather_pages``), ``kv.d2h`` (the blocking
-        copies)."""
+        """Pages ``ids`` as contiguous (L, pow2(n), page, KV, Dh) host
+        buffers, the padded slots repeating the last page: one gather
+        kernel per pool, at a power-of-two width so tier moves compile
+        O(log) shapes, then one D2H copy. Spans: ``kv.gather`` (the two
+        dispatches; their device work is the trace's
+        ``jit_gather_pages``), ``kv.d2h`` (the blocking copies)."""
         (pad,) = _pad_ids(list(ids), _pow2(len(ids)))
         with span("kv.gather"):
             pad = jnp.asarray(pad)
             k = gather_pages(self.k_pages, pad, interpret=self.interpret)
             v = gather_pages(self.v_pages, pad, interpret=self.interpret)
         with span("kv.d2h"):
-            k, v = np.asarray(k), np.asarray(v)
-        return k[:, :len(ids)], v[:, :len(ids)]
+            return np.asarray(k), np.asarray(v)
 
     def stage_out(self, program_id: str
                   ) -> tuple[np.ndarray, np.ndarray, int]:
         """The program's scattered pages as contiguous host staging
-        buffers (:meth:`read_pages`) — the unit a tier move DMAs to host
-        DRAM in one transfer — and its length."""
+        buffers (:meth:`read_pages`, padding included, so
+        :meth:`restore` copies them back as they are) — the unit a tier
+        move DMAs to host DRAM in one transfer — and its length."""
         e = self.programs[program_id]
         return (*self.read_pages(e.pages), e.length)
 
     def restore(self, program_id: str, k_staging, v_staging,
                 length: int) -> list[int]:
-        """Scatter reloaded contiguous (host) staging buffers into freshly
-        allocated physical pages (the H2D leg of a promotion), padded to a
-        power-of-two width by repeating the last page. Spans, once per
-        pool: ``kv.restore_pad`` (the padded host copy), ``kv.h2d`` (its
-        copy to the device), ``kv.scatter`` (the dispatch; its device work
-        is the trace's ``jit_scatter_pages``)."""
+        """Scatter staged buffers (:meth:`stage_out`'s, W pages wide, W a
+        power of two) back into freshly allocated physical pages: the
+        leading ``ceil(length / page)`` = n pages, fewer than were staged
+        where the usable prefix shrank. Each buffer goes to the device as
+        it is, with no host copy (``kv.h2d``, once per pool: the copy's
+        dispatch, as the transfer completes asynchronously); one dispatch
+        for both pools (``kv.scatter``, the trace's ``jit__restore_pages``)
+        pads on the device, slot i carrying staged page min(i, n - 1)."""
+        W = k_staging.shape[1]
+        n = math.ceil(length / self.page_size)
+        assert W == _pow2(W) and 0 < n <= W, (W, n)
         stale = self.programs.pop(program_id, None)
         if stale is not None:           # defensive: never leak pages
             for pi in stale.pages:
                 self._deref(pi)
-        n = k_staging.shape[1]
         pages: list[int] = []
         try:
             for _ in range(n):
@@ -355,25 +375,17 @@ class PagedKVRuntime:
             for pi in pages:
                 self._deref(pi)
             raise
-        (ids,) = _pad_ids(pages, _pow2(n))
-        take = np.minimum(np.arange(len(ids)), n - 1)
-        ids = jnp.asarray(ids)
-        # pool by pool: k's copy to the device overlaps v's padding
-        self.k_pages = self._restore_pool(self.k_pages, k_staging, take, ids)
-        self.v_pages = self._restore_pool(self.v_pages, v_staging, take, ids)
+        (ids,) = _pad_ids(pages, W)
+        with span("kv.h2d"):
+            k = jax.device_put(k_staging)
+        with span("kv.h2d"):
+            v = jax.device_put(v_staging)
+        with span("kv.scatter"):
+            self.k_pages, self.v_pages = _restore_pages(
+                self.k_pages, self.v_pages, k, v, jnp.asarray(ids),
+                jnp.asarray(n, jnp.int32), interpret=self.interpret)
         self.programs[program_id] = ProgramEntry(pages, length)
         return pages
-
-    def _restore_pool(self, pool, staging, take, ids):
-        """One pool's leg of :meth:`restore`: the staging buffer padded on
-        the host by ``take``, copied to the device, scattered into
-        ``pool`` at ``ids``."""
-        with span("kv.restore_pad"):
-            padded = np.asarray(staging)[:, take]
-        with span("kv.h2d"):
-            padded = jnp.asarray(padded)
-        with span("kv.scatter"):
-            return scatter_pages(pool, padded, ids, interpret=self.interpret)
 
     # ----------------------------------------------------------- prefill
     def prefill(self, params, program_id: str, tokens: jax.Array,

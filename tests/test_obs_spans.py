@@ -30,9 +30,9 @@ REQUIRED = {
                         "prefill_reload", "t_bar"},
     "kv.stage_out": {"program", "pages", "bytes", "padded_bytes"},
     "kv.gather": set(), "kv.d2h": set(),
-    "kv.restore": {"program", "pages", "bytes", "padded_bytes",
-                   "priced_s"},
-    "kv.restore_pad": set(), "kv.h2d": set(), "kv.scatter": set(),
+    "kv.restore": {"program", "pages", "staged_pages", "bytes",
+                   "padded_bytes", "priced_s"},
+    "kv.h2d": set(), "kv.scatter": set(),
     "kv.cow_split": {"src_page"},
     "model.prefill": {"program", "start", "tokens", "pad_to"},
     "model.decode": {"rows", "n_tab"},
@@ -148,14 +148,19 @@ def test_restore_bytes_are_the_staged_pages(runs):
     (rst,) = [a for name, _, _, a in spans if name == "kv.restore"]
     (out,) = [a for name, _, _, a in spans if name == "kv.stage_out"]
     pages = math.ceil(n / 16)
-    assert rst["pages"] == out["pages"] == pages == k.shape[1]
-    assert rst["bytes"] == out["bytes"] == k.nbytes + v.nbytes
     width = 1 << (pages - 1).bit_length()
+    # the host copy is the gather's whole power-of-two buffer; the spans
+    # count its real pages and their padded width
+    assert k.shape[1] == v.shape[1] == width
+    real = k[:, :pages].nbytes + v[:, :pages].nbytes
+    assert rst["pages"] == rst["staged_pages"] == out["pages"] == pages
+    assert rst["bytes"] == out["bytes"] == real
     assert rst["padded_bytes"] == out["padded_bytes"] \
-        == (k.nbytes + v.nbytes) // pages * width
+        == k.nbytes + v.nbytes == real // pages * width
     be = eng.backend
-    assert be.restore_bytes == be.stage_out_bytes == k.nbytes + v.nbytes
+    assert be.restore_bytes == be.stage_out_bytes == real
     assert be.restore_seconds > 0 and be.stage_out_seconds > 0
+    assert be.restores_truncated == 0
 
 
 def test_spans_nest_as_the_calls_do(runs):
@@ -168,12 +173,13 @@ def test_spans_nest_as_the_calls_do(runs):
     assert len(h2d) == 2                    # one per pool, k then v
     (rst,) = inside(h2d[0], "kv.restore")
     assert inside(h2d[1], "kv.restore") == [rst]
+    (scatter,) = [s for s in spans if s[0] == "kv.scatter"]
+    assert inside(scatter, "kv.restore") == [rst]
     (adm,) = inside(rst, "engine.admit")
     (step,) = inside(adm, "engine.step")
     assert inside(rst, "sched.admit") and inside(rst, "sched.schedule")
-    # k's copy to the device is dispatched before v's padding starts
-    pads = [s for s in spans if s[0] == "kv.restore_pad"]
-    assert pads[0][2] <= h2d[0][1] <= h2d[0][2] <= pads[1][1]
+    # both pools' copies to the device precede the one scatter dispatch
+    assert h2d[0][2] <= h2d[1][1] <= h2d[1][2] <= scatter[1]
     (d2h,) = [s for s in spans if s[0] == "kv.d2h"]
     (out,) = inside(d2h, "kv.stage_out")
     assert inside(out, "sched.retention") and inside(out, "engine.advance")
