@@ -82,8 +82,8 @@ class Capture:
         self.log.append(("stage", pid, list(self.rt.programs[pid].pages)))
         return self._orig["stage_out"](pid)
 
-    def _restore(self, pid, k, v, length):
-        pages = self._orig["restore"](pid, k, v, length)
+    def _restore(self, pid, *staged):
+        pages = self._orig["restore"](pid, *staged)
         self.log.append(("restore", pid, list(pages)))
         return pages
 

@@ -1,5 +1,21 @@
 """Weights from the seed, and the plain reference forward that decides
-``correct``.
+``correct``: the reference module of dense transformer configurations.
+
+A configuration file names its reference module by ``"reference":
+"<stem>"`` (the module ``benchmark/<stem>.py``; this one, ``reference``,
+where the key is absent). ``run.py`` knows an architecture only through
+that module's interface:
+
+- ``shape_of(model) -> dims``: the hashable dims the forward needs, from
+  the file's ``model`` block;
+- ``served_dims(cfg) -> dims``: the same dims, read from the registry
+  ``ModelConfig`` the program serves;
+- ``program_config(cfg, model) -> ModelConfig``: the registry config cut
+  to the file's sizes;
+- ``make_weights(dims, seed, embed_std)``: the weights the program is
+  handed, in the tree it serves;
+- ``gaps(dims, params, seq, at, tok, pad_to, control=False)``: each
+  served token's gap below the reference's best (below).
 
 Nothing here imports the system under test. The weights are made by the
 benchmark (one jitted call, on the device, in float32 as the program
@@ -19,6 +35,7 @@ change might take.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -45,6 +62,19 @@ def shape_of(model: dict) -> tuple:
             float(model.get("rms_norm_eps", model.get("layer_norm_eps"))),
             bool(model["tie_word_embeddings"]),
             bool(model.get("use_qkv_bias", model.get("qkv_bias", False))))
+
+
+def served_dims(cfg) -> tuple:
+    """The dims of ``shape_of`` read from a registry ``ModelConfig``."""
+    return (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+            cfg.head_dim, cfg.d_ff, cfg.vocab_size, float(cfg.rope_theta),
+            float(cfg.rope_fraction), float(cfg.norm_eps),
+            bool(cfg.tie_embeddings), bool(cfg.qkv_bias))
+
+
+def program_config(cfg, model: dict):
+    """The registry config cut to the file's sizes: its depth."""
+    return dataclasses.replace(cfg, num_layers=model["num_hidden_layers"])
 
 
 def root_key(seed: int) -> jax.Array:

@@ -16,9 +16,12 @@ With ``--trace 1`` a profiler trace covers the window and the run
 reports the per-layer metrics instead.
 
 After the window the harness compares what the timed path produced with
-a float32 reference (``capture.py``, ``reference.py``) and prints each
+a float32 reference (``capture.py``; the reference module the
+configuration file names, ``reference.py`` by default) and prints each
 compared number beside its limit, last on standard error and last in
-the result. The result is the last line of standard output.
+the result. The result is the last line of standard output. The harness
+knows an architecture only through that module: a new one joins by a
+new module, a new configuration file and a new cell.
 
 ``--rehearsal`` runs the same path on the CPU at the registry's smoke
 size with interpreted kernels; it is not a measurement and prints no
@@ -37,6 +40,7 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import pathlib  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -49,7 +53,6 @@ import numpy as np  # noqa: E402
 
 import counts  # noqa: E402
 import driver as drv  # noqa: E402
-import reference  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic_gen  # noqa: E402
 import warmup  # noqa: E402
@@ -103,17 +106,37 @@ def rehearsal_traffic(traffic: dict, f: float, ramp_s: float) -> dict:
     return t
 
 
-def dims_of(cfg) -> tuple:
-    """The reference's dims of a registry config."""
-    return (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-            cfg.head_dim, cfg.d_ff, cfg.vocab_size, float(cfg.rope_theta),
-            float(cfg.rope_fraction), float(cfg.norm_eps),
-            bool(cfg.tie_embeddings), bool(cfg.qkv_bias))
+def reference_of(cfgf: dict):
+    """The reference module a configuration file names by its
+    ``reference`` key (``benchmark/<stem>.py``; ``reference`` where the
+    key is absent), loaded by path. Its interface is in ``reference.py``'s
+    docstring."""
+    stem = cfgf.get("reference", "reference")
+    path = HERE / f"{stem}.py"
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", stem) or \
+            not path.is_file():
+        raise Failure(f"no reference module {stem!r} "
+                      f"(benchmark/{stem}.py) for {cfgf.get('name')!r}")
+    name = f"bench_reference_{stem}"
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+    return sys.modules[name]
+
+
+def pools(rt) -> dict:
+    """The runtime's KV pools: its array attributes named ``*_pages``."""
+    import jax
+    return {k: v for k, v in vars(rt).items()
+            if k.endswith("_pages") and isinstance(v, jax.Array)}
 
 
 def build(cfgf: dict, seed: int, rehearsal: bool):
     """The engine of the normal entry point, at the configuration file's
-    sizes, holding the benchmark's weights from ``seed``."""
+    sizes, holding the benchmark's weights from ``seed``; with the
+    reference module and its dims."""
     import jax
     from repro.configs import get_config
     from repro.core.ttl import TTLConfig
@@ -122,6 +145,7 @@ def build(cfgf: dict, seed: int, rehearsal: bool):
     from repro.serving.offload import OffloadConfig
     from repro.serving.prefix import PrefixConfig
 
+    ref = reference_of(cfgf)
     sv = dict(cfgf["serve"])
     cfg = get_config(cfgf["registry"], smoke=rehearsal)
     budget = (sv.get("kv_budget_gb") or 0.0) * 1e9    # 0: the default
@@ -129,16 +153,15 @@ def build(cfgf: dict, seed: int, rehearsal: bool):
         sv.update(REHEARSAL)
         budget = sv["kv_blocks"] * sv["block_size"] * cfg.kv_bytes_per_token(2)
     else:
-        cfg = dataclasses.replace(
-            cfg, num_layers=cfgf["model"]["num_hidden_layers"])
-        want = reference.shape_of(cfgf["model"])
-        if dims_of(cfg) != want:
+        cfg = ref.program_config(cfg, cfgf["model"])
+        want = ref.shape_of(cfgf["model"])
+        if ref.served_dims(cfg) != want:
             raise Failure(f"registry {cfgf['registry']} serves "
-                          f"{dims_of(cfg)}, the file states {want}")
+                          f"{ref.served_dims(cfg)}, the file states {want}")
         for k, v in cfgf["precision"].items():
             if (getattr(cfg, k) or cfg.compute_dtype) != v:
                 raise Failure(f"{k}: program {getattr(cfg, k)!r}, file {v!r}")
-    dims = dims_of(cfg)
+    dims = ref.served_dims(cfg)
     ecfg = EngineConfig(
         policy=sv["policy"], chips=1, max_batch=sv["max_batch"],
         chunk_size=sv["chunk_size"], block_size=sv["block_size"],
@@ -154,15 +177,14 @@ def build(cfgf: dict, seed: int, rehearsal: bool):
     # the program's own weights make way for the benchmark's
     shapes = jax.tree.map(lambda x: (x.shape, x.dtype), be.params)
     be.params = None
-    params = reference.make_weights(dims, seed,
-                                    cfgf["model"]["initializer_range"])
+    params = ref.make_weights(dims, seed, cfgf["model"]["initializer_range"])
     got = jax.tree.map(lambda x: (x.shape, x.dtype), params)
     if got != shapes:
         raise Failure(f"weight layout differs from the program's: "
                       f"{got} vs {shapes}")
     be.params = params
     jax.block_until_ready(params)
-    return eng, sv, dims
+    return eng, sv, ref, dims
 
 
 def warm(eng, sv: dict, progs: list, rows: int) -> None:
@@ -186,7 +208,7 @@ def warm(eng, sv: dict, progs: list, rows: int) -> None:
     for name, fn in phases:
         t, c = time.perf_counter(), compile_stats()
         fn()
-        jax.block_until_ready((rt.k_pages, rt.v_pages))
+        jax.block_until_ready(pools(rt))
         c1 = compile_stats()
         say(f"warm-up {name}: {time.perf_counter() - t:.3f} s, compiling "
             f"{c1['seconds'] - c['seconds']:.3f} s, cache hits "
@@ -236,6 +258,7 @@ class View:
     c0: dict                 # counters at the window's start and end
     c1: dict
     capture: Capture
+    reference: object        # the configuration's reference module
     dims: tuple
     page: int
     peaks: dict
@@ -288,8 +311,8 @@ def pick_programs(d: drv.Driver, n: int, seed: int
     return picked + rest[:max(0, n - len(picked))], why
 
 
-def compare(cap: Capture, pids: list[str], dims, params, max_len: int,
-            control: bool = False) -> dict:
+def compare(cap: Capture, pids: list[str], ref, dims, params,
+            max_len: int, control: bool = False) -> dict:
     """The widest gap, in standard deviations of the reference's logits,
     by which a token the program produced lies below the reference's best
     at its position, over every token of the programs ``pids``.
@@ -305,14 +328,14 @@ def compare(cap: Capture, pids: list[str], dims, params, max_len: int,
     for pid in pids:
         for c in chains.get(pid, []):
             at, tok = np.asarray(c.at), np.asarray(c.tok)
-            g = reference.gaps(dims, params, c.seq, at, tok, max_len)
+            g = ref.gaps(dims, params, c.seq, at, tok, max_len)
             out["tokens"] += len(g)
             out["decode_rows"].update(r for r in c.decode_rows if r)
             if control:
                 out["program_max_gap_sd"] = max(out["program_max_gap_sd"],
                                                 float(g.max()))
-                g = reference.gaps(dims, params, c.seq, at, tok, max_len,
-                                   control=True)
+                g = ref.gaps(dims, params, c.seq, at, tok, max_len,
+                             control=True)
             out["max_gap_sd"] = max(out["max_gap_sd"], float(g.max()))
     out["decode_rows"] = sorted(out["decode_rows"])
     return out
@@ -351,7 +374,7 @@ def run(argv=None, on_engine=None, control: bool = False) -> dict:
     say(f"device: platform={dev.platform} kind={dev.device_kind!r} "
         f"count={len(devs)}")
 
-    eng, sv, dims = build(cfgf, args.seed, args.rehearsal)
+    eng, sv, ref, dims = build(cfgf, args.seed, args.rehearsal)
     if args.rehearsal:
         traffic = rehearsal_traffic(
             traffic, sv["max_len"] / cfgf["serve"]["max_len"], sv["ramp_s"])
@@ -383,7 +406,7 @@ def run(argv=None, on_engine=None, control: bool = False) -> dict:
     d.run_until(w0)
     c0 = counters(eng)
     k0 = compile_stats()
-    view = View(d, w0, w1, c0, {}, cap, dims, rt.page_size,
+    view = View(d, w0, w1, c0, {}, cap, ref, dims, rt.page_size,
                 {} if args.rehearsal else counts.peaks(dev.device_kind))
     if args.trace:
         view.table = trace_reduce.names()
@@ -459,10 +482,11 @@ def run(argv=None, on_engine=None, control: bool = False) -> dict:
             f"{device['window_s']:.6f} s")
 
     # correctness: free the serving state, then the reference
-    rt.k_pages = rt.v_pages = None
+    for name in pools(rt):
+        setattr(rt, name, None)
     be.host_caches.clear()
     pids, why = pick_programs(d, CHECK_PROGRAMS, args.seed)
-    res = compare(cap, pids, dims, be.params, sv["max_len"], control)
+    res = compare(cap, pids, ref, dims, be.params, sv["max_len"], control)
     limit = cfgf["check"]["max_gap_sd"]
     say(f"compared {res['tokens']} served tokens of {len(pids)} programs "
         f"({', '.join(pids)}; by path {why}); decode batch sizes "

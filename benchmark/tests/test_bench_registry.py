@@ -13,8 +13,13 @@ import pytest
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+sys.path.insert(0, str(BENCH))
+
+import run  # noqa: E402
+
 CELLS = [w["name"] for w in
          json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
+CONFIGS = sorted((BENCH / "configs").glob("*.json"))
 
 PROBE = r"""
 import json, sys
@@ -65,6 +70,78 @@ def test_new_files_are_found_by_name(tmp_path):
                    "metric": 42}
     for p, b in before.items():
         assert p.read_bytes() == b, f"{p} was edited"
+
+
+# a new architecture's reference module: here the dense one, re-exported,
+# with each call of ``gaps`` recorded in the file {log!r}
+PROBE_MODULE = '''"""The dense reference, each ``gaps`` call recorded."""
+from reference import *  # noqa: F401,F403
+from reference import gaps as _gaps
+
+
+def gaps(*args, **kw):
+    with open({log!r}, "a") as f:
+        f.write("gaps\\n")
+    return _gaps(*args, **kw)
+'''
+
+
+def test_a_new_reference_module_is_found_by_name(tmp_path):
+    """A copy of the benchmark gains a reference module, a configuration
+    that names it and a cell, by new files and new entries only; the
+    unchanged harness serves the cell through the CPU rehearsal, compares
+    with that module, and finds the run correct."""
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    before = {p: p.read_bytes() for p in (root / "benchmark").rglob("*")
+              if p.is_file()}
+    log = tmp_path / "gaps.log"
+    (root / "benchmark" / "reference_probe.py").write_text(
+        PROBE_MODULE.format(log=str(log)))
+    cfg = json.loads((BENCH / "configs" / "stablelm-3b-pp2.json")
+                     .read_text())
+    cfg.update(name="new-arch", reference="reference_probe")
+    (root / "benchmark" / "configs" / "new-arch.json").write_text(
+        json.dumps(cfg))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "new-arch", "source": cfg["source"],
+                             "file": "benchmark/configs/new-arch.json",
+                             "reduced": cfg["reduced"], "why": "test"})
+    bench["workloads"].append({"name": "new-arch.swe-workers",
+                               "config": "new-arch",
+                               "traffic": "swe-workers", "chips": 1,
+                               "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    # the copy holds no program: it comes from the repository's src
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"))
+    out = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload",
+         "new-arch.swe-workers", "--seed", "11", "--seconds", "8",
+         "--rehearsal"], cwd=root, capture_output=True, text=True, env=env,
+        timeout=240)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["rehearsal"] is True and got["correct"] is True, got
+    assert log.read_text().count("gaps") > 0, "the probe's gaps never ran"
+    for p, b in before.items():
+        assert p.read_bytes() == b, f"{p} was edited"
+
+
+def test_a_reference_module_with_no_file_fails_with_its_name():
+    with pytest.raises(run.Failure, match="no_such_reference"):
+        run.reference_of({"name": "x", "reference": "no_such_reference"})
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+def test_served_dims_of_the_cut_config_are_the_files(path):
+    """The registry config, cut by the file's reference module, serves
+    the dims the file states (no engine is built)."""
+    from repro.configs import get_config
+    cfgf = json.loads(path.read_text())
+    ref = run.reference_of(cfgf)
+    cfg = ref.program_config(get_config(cfgf["registry"]), cfgf["model"])
+    assert ref.served_dims(cfg) == ref.shape_of(cfgf["model"])
 
 
 def test_every_listed_file_exists():

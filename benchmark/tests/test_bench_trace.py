@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import json
 import pathlib
+import shutil
 import sys
 
 import pytest
@@ -116,3 +117,46 @@ def test_decode_step_flops_by_hand():
     assert counts.matmul_params(dims) == L * per_layer + D * V
     f = counts.decode_step_flops(dims, [3, 5])
     assert f == 2 * (L * per_layer + D * V) * 2 + 4 * L * H * Dh * (4 + 6)
+
+
+def _name_files(root: pathlib.Path, added: dict) -> pathlib.Path:
+    """``root`` holding a copy of the name table and the ``added`` files
+    (file name -> contents)."""
+    shutil.copy(BENCH / "kernel_names.json", root)
+    for name, table in added.items():
+        (root / name).write_text(json.dumps(table))
+    return root
+
+
+def test_name_files_add_roles(tmp_path):
+    """A ``kernel_names.<name>.json`` adds roles by a new file; the
+    table's own roles stay as they are."""
+    root = _name_files(tmp_path, {
+        "kernel_names.mla.json": {
+            "programs": {"mla_decode_step": ["jit__mla_decode*"]},
+            "kernels": {"mla_decode_attention": ["%mla_decode*"]}},
+        "kernel_names.moe.json": {"kernels": {"moe_gmm": ["%gmm*"]}}})
+    table = tr.names(root)
+    assert table["programs"] == {**TABLE["programs"],
+                                 "mla_decode_step": ["jit__mla_decode*"]}
+    assert table["kernels"] == {**TABLE["kernels"],
+                                "mla_decode_attention": ["%mla_decode*"],
+                                "moe_gmm": ["%gmm*"]}
+    assert {k: v for k, v in table.items() if k not in tr.ROLE_GROUPS} == \
+        {k: v for k, v in TABLE.items() if k not in tr.ROLE_GROUPS}
+    ev = [(OPS, "%gmm.3 = custom-call()", 0, 700)]
+    assert tr.kernel_time(ev, table, "moe_gmm") == (7e-7, 1)
+
+
+@pytest.mark.parametrize("added,err", [
+    ({"kernel_names.a.json": {"kernels": {"decode_attention": ["%x*"]}}},
+     "kernels role 'decode_attention' is defined twice"),
+    ({"kernel_names.a.json": {"programs": {"p": ["a*"]}},
+      "kernel_names.b.json": {"programs": {"p": ["b*"]}}},
+     "kernel_names.b.json: programs role 'p' is defined twice"),
+    ({"kernel_names.a.json": {"ops_line": "XLA Ops"}},
+     "keys other than"),
+], ids=["table-role", "two-files", "other-key"])
+def test_a_role_defined_twice_is_an_error(tmp_path, added, err):
+    with pytest.raises(ValueError, match=err):
+        tr.names(_name_files(tmp_path, added))

@@ -3,7 +3,8 @@
 A trace is read into plain events, ``(line, name, start_ns, dur_ns)``
 per device plane, so the arithmetic below runs on a recorded trace and
 on synthetic ones alike. Which events belong to which program or kernel
-is decided by the name table ``kernel_names.json`` beside this file.
+is decided by the name table ``kernel_names.json`` beside this file, and
+by the roles each ``kernel_names.<name>.json`` beside it adds.
 """
 from __future__ import annotations
 
@@ -16,8 +17,27 @@ import pathlib
 HERE = pathlib.Path(__file__).resolve().parent
 
 
-def names() -> dict:
-    return json.loads((HERE / "kernel_names.json").read_text())
+ROLE_GROUPS = ("programs", "kernels")
+
+
+def names(here: pathlib.Path = HERE) -> dict:
+    """The name table ``kernel_names.json`` in ``here``, with the roles
+    that every ``kernel_names.*.json`` there adds under ``programs`` and
+    ``kernels``, so a new kernel's trace names come in a new file. A role
+    defined twice, or another key in an added file, is an error."""
+    table = json.loads((here / "kernel_names.json").read_text())
+    for path in sorted(here.glob("kernel_names.*.json")):
+        extra = json.loads(path.read_text())
+        if set(extra) - set(ROLE_GROUPS):
+            raise ValueError(f"{path.name}: keys other than {ROLE_GROUPS}: "
+                             f"{sorted(set(extra) - set(ROLE_GROUPS))}")
+        for group, roles in extra.items():
+            for role, pats in roles.items():
+                if role in table[group]:
+                    raise ValueError(f"{path.name}: {group} role {role!r} "
+                                     f"is defined twice")
+                table[group][role] = pats
+    return table
 
 
 def load(trace_dir: str) -> tuple[dict[str, list[tuple]], list[tuple]]:

@@ -165,9 +165,9 @@ def warm_tiers(rt, max_pages: int) -> None:
         sc = Scratch(rt)
         pid = sc.entry(n * rt.page_size, list(range(1, n + 1)))
         rt.free[:] = [p for p in rt.free if p not in rt.refs]
-        k, v, length = rt.stage_out(pid)
+        staged = rt.stage_out(pid)
         rt.evict(pid, force=True)
-        rt.restore(pid, k, v, length)
+        rt.restore(pid, *staged)
         sc.close()
         n *= 2
     sc = Scratch(rt)
