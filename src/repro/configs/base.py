@@ -24,10 +24,54 @@ class MoEConfig:
     first_k_dense: int = 0    # leading dense layers (Moonlight-style)
     dense_d_ff: int = 0       # d_ff of the leading dense layers
     router_dtype: str = "float32"
+    # router: "softmax" over the top-k logits (Qwen3/Mixtral), or
+    # "sigmoid" scores with a per-expert correction bias that chooses the
+    # experts but does not weight them (DeepSeek-V3 ``noaux_tc``)
+    scoring: str = "softmax"
+    norm_topk: bool = True         # normalise the chosen weights to sum 1
+    routed_scaling: float = 1.0    # then scale them (Moonlight: 2.446)
+    # the experts this chip holds: [held_start, held_start + held); 0 =
+    # all. The router still scores every expert; copies routed to an
+    # expert held elsewhere add nothing here (expert parallelism without
+    # its exchange)
+    held: int = 0
+    held_start: int = 0
     # "tp": experts replicated, expert-mlp dim sharded over model axis.
     # "ep": experts sharded over model axis (GSPMD inserts dispatch comms).
     # "ep_a2a": shard_map all-to-all expert parallelism (beyond-paper path).
     sharding_mode: str = "ep"
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): the cache keeps one
+    normalised latent ``kv_lora_rank`` wide and one rotary key
+    ``qk_rope_head_dim`` wide per token and layer, shared by all heads."""
+    kv_lora_rank: int = 512
+    q_lora_rank: int = 0           # 0: queries straight from x
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        """The cache row as stored: ``cache_dim`` zero-padded to the
+        chip's 128 lanes. A row-major (…, page, 576) bf16 array occupies
+        640 lanes anyway, and an unpadded pool gets a page-minor layout
+        that costs a relayout copy of the whole pool at every kernel."""
+        return -(-self.cache_dim // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,6 +119,7 @@ class ModelConfig:
     scale_embeddings: bool = False     # gemma2: x *= sqrt(d_model)
     # --- family-specific ---
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None
     ssm: SSMConfig | None = None
     rwkv: RWKVConfig | None = None
     shared_attn_every: int = 0         # zamba2: shared attn block cadence
@@ -126,12 +171,19 @@ class ModelConfig:
             if i < m.first_k_dense:
                 n += 3 * D * m.dense_d_ff
             else:
-                active = m.top_k + m.num_shared_experts
+                active = min(m.top_k, m.n_held) + m.num_shared_experts
                 n += 3 * D * m.d_ff_expert * active + D * m.num_experts  # + router
+                if m.scoring == "sigmoid":
+                    n += m.num_experts               # correction bias
         return n
 
     def _attn_params(self) -> int:
         D, H, KV, Dh = self.d_model, self.num_heads, self.num_kv_heads, self.head_dim
+        if self.mla is not None:
+            a = self.mla
+            return (D * H * a.qk_head_dim + D * a.cache_dim + a.kv_lora_rank
+                    + a.kv_lora_rank * H * (a.qk_nope_head_dim + a.v_head_dim)
+                    + H * a.v_head_dim * D)
         n = D * H * Dh + 2 * D * KV * Dh + H * Dh * D
         if self.qkv_bias:
             n += (H + 2 * KV) * Dh
@@ -179,8 +231,10 @@ class ModelConfig:
             if i < m.first_k_dense:
                 n += 3 * D * m.dense_d_ff
             else:
-                n += 3 * D * m.d_ff_expert * (m.num_experts + m.num_shared_experts)
+                n += 3 * D * m.d_ff_expert * (m.n_held + m.num_shared_experts)
                 n += D * m.num_experts
+                if m.scoring == "sigmoid":
+                    n += m.num_experts               # correction bias
         else:
             n += 3 * D * self.d_ff
         return n
@@ -189,6 +243,8 @@ class ModelConfig:
         """KV-cache bytes per token per sequence (serving cost model input)."""
         if self.family == "ssm":
             return 0  # constant-size state, not per-token
+        if self.mla is not None:         # one latent + rotary key, all heads
+            return self.num_layers * self.mla.cache_width * dtype_bytes
         per_layer = 2 * self.num_kv_heads * self.head_dim * dtype_bytes
         if self.family == "hybrid":
             n_attn = (self.num_layers // self.shared_attn_every) if self.shared_attn_every else 0

@@ -156,6 +156,8 @@ def default_rules(cfg: ModelConfig, mesh, step_kind: str = "train") -> dict:
         "q_heads": "model",
         "kv_heads": "model" if KV % msize == 0 else None,
         "head_dim": None,
+        # latent attention's shared latent (kv_lora + rope) dim: replicated
+        "kv_latent": None,
         "mlp": "model",
         "embed_out": "model",
         "vocab": "model",

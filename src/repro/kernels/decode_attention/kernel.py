@@ -39,6 +39,11 @@ the sentinel themselves (the paged runtime does) pass through unchanged.
 
 VMEM per step: page (page, KV, D)*2 + q (KV, G, D) + acc fp32 ≈
 page=64, KV=8, D=128, G=4: ~600 KB.
+
+The latent mode (``paged_mla_decode_kernel``) serves absorbed
+multi-head latent attention: one pool whose row [c, k_pe] is the key of
+every head and whose leading columns c are the value, so each grid step
+makes one DMA; the same grid, table contract and online softmax.
 """
 from __future__ import annotations
 
@@ -67,9 +72,12 @@ def sanitize_block_tables(block_tables, seq_lens, page: int) -> jnp.ndarray:
     return jnp.where(live, block_tables, 0).astype(jnp.int32)
 
 
-def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref, *,
-                   scale: float, page: int, n_pages: int, normalize: bool):
+def _paged_step(lens_ref, m_ref, l_ref, acc_ref, o_ref, m_out_ref,
+                l_out_ref, scores, *, page: int, n_pages: int,
+                normalize: bool):
+    """The online softmax over a row's pages, shared by both modes:
+    ``scores()`` gives this page's (KV, G, page) scores and a function
+    of the probabilities giving their (KV, G, Dv) weighted values."""
     b = pl.program_id(0)
     ip = pl.program_id(1)
 
@@ -84,12 +92,7 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)               # (KV, G, D)
-        k = k_ref[0, 0].astype(jnp.float32)            # (page, KV, D)
-        v = v_ref[0, 0].astype(jnp.float32)
-        # batched over kv heads: (KV,G,D) x (page,KV,D) -> (KV,G,page)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,)))) * scale
+        s, weigh = scores()
         pos = ip * page + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
         s = jnp.where(pos < seq_len, s, NEG_INF)
 
@@ -97,9 +100,8 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
         p = jnp.exp(s - m_new[:, :, None])
         l_ref[...] = l_ref[...] * jnp.exp(m_prev - m_new) + jnp.sum(p, axis=2)
-        # (KV,G,page) x (page,KV,D) -> (KV,G,D)
-        pv = jax.lax.dot_general(p, v, (((2,), (0,)), ((0,), (1,))))
-        acc_ref[...] = acc_ref[...] * jnp.exp(m_prev - m_new)[:, :, None] + pv
+        acc_ref[...] = acc_ref[...] * jnp.exp(m_prev - m_new)[:, :, None] \
+            + weigh(p)
         m_ref[...] = m_new
 
     @pl.when(ip == n_pages - 1)
@@ -111,6 +113,43 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
             o_ref[0] = (acc_ref[...] / l[:, :, None]).astype(o_ref.dtype)
         else:
             o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
+                   o_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref, *,
+                   scale: float, page: int, n_pages: int, normalize: bool):
+    def scores():
+        q = q_ref[0].astype(jnp.float32)               # (KV, G, D)
+        k = k_ref[0, 0].astype(jnp.float32)            # (page, KV, D)
+        v = v_ref[0, 0].astype(jnp.float32)
+        # batched over kv heads: (KV,G,D) x (page,KV,D) -> (KV,G,page)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (1,)))) * scale
+        # (KV,G,page) x (page,KV,D) -> (KV,G,D)
+        return s, lambda p: jax.lax.dot_general(
+            p, v, (((2,), (0,)), ((0,), (1,))))
+
+    _paged_step(lens_ref, m_ref, l_ref, acc_ref, o_ref, m_out_ref,
+                l_out_ref, scores, page=page, n_pages=n_pages,
+                normalize=normalize)
+
+
+def _latent_kernel(tables_ref, lens_ref, layer_ref, q_ref, c_ref,
+                   o_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref, *,
+                   scale: float, page: int, n_pages: int, value_dim: int):
+    def scores():
+        q = q_ref[0, 0].astype(jnp.float32)            # (H, D)
+        c = c_ref[0, 0].astype(jnp.float32)            # (page, D)
+        # every head against the one latent key: (H,D) x (page,D) -> (H,page)
+        s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ()))) * scale
+        # the value is the key's leading value_dim columns: (H,page) x
+        # (page,Dv) -> (H,Dv), from the same page fetch
+        return s[None], lambda p: jax.lax.dot_general(
+            p[0], c[:, :value_dim], (((1,), (0,)), ((), ())))[None]
+
+    _paged_step(lens_ref, m_ref, l_ref, acc_ref, o_ref, m_out_ref,
+                l_out_ref, scores, page=page, n_pages=n_pages,
+                normalize=False)
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, seq_lens,
@@ -192,3 +231,63 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, seq_lens,
     if return_residuals:
         return out, m, l
     return out.reshape(B, H, D)
+
+
+def paged_mla_decode_kernel(q, c_pages, block_tables, seq_lens, *,
+                            layer=None, scale: float, value_dim: int,
+                            interpret: bool | None = None):
+    """The latent mode: absorbed multi-head latent attention decode.
+    q (B, H, D) — each head's query in the latent space, [q_nope W_kb^T,
+    q_pe]; c_pages (P, page, D) or layer-stacked (L, P, page, D), one
+    row [c, k_pe] per token shared by every head; its leading
+    ``value_dim`` columns (c) are also the value, so a page is fetched
+    once for both. Same grid, table sanitizing and online softmax as the
+    grouped-query mode; ``scale`` is given (1/sqrt of the unabsorbed
+    query-key width).
+
+    Returns the UNnormalized fp32 accumulator (B, H, value_dim) and the
+    per-head running max and denominator (B, H), for the caller to merge
+    the new token's own row and normalize."""
+    interpret = resolve_interpret(interpret)
+    if c_pages.ndim == 3:
+        c_pages = c_pages[None]
+    L, P, page, D = c_pages.shape
+    B, H, _ = q.shape
+    n_pages = block_tables.shape[1]
+    assert n_pages >= 1, "block table must cover at least one page"
+    seq_lens = jnp.asarray(seq_lens, jnp.int32)
+    tables = sanitize_block_tables(block_tables, seq_lens, page)
+    lay = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
+    kern = functools.partial(_latent_kernel, scale=scale, page=page,
+                             n_pages=n_pages, value_dim=value_dim)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,             # block_tables, seq_lens, layer
+        grid=(B, n_pages),
+        in_specs=[
+            pl.BlockSpec((1, 1, H, D),
+                         lambda b, ip, tab, lens, lay: (b, 0, 0, 0)),
+            # one DMA per page: the latent row is key and value both
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, ip, tab, lens, lay:
+                         (lay[0], tab[b, ip], 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, H, value_dim),
+                         lambda b, ip, tab, lens, lay: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, H), lambda b, ip, tab, lens, lay: (b, 0, 0)),
+            pl.BlockSpec((1, 1, H), lambda b, ip, tab, lens, lay: (b, 0, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((1, H), jnp.float32),
+            pltpu.VMEM((1, H), jnp.float32),
+            pltpu.VMEM((1, H, value_dim), jnp.float32),
+        ],
+    )
+    acc, m, l = pl.pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, 1, H, value_dim), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, H), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, H), jnp.float32)],
+        interpret=interpret,
+    )(tables, seq_lens, lay, q.reshape(B, 1, H, D), c_pages)
+    return acc[:, 0], m[:, 0], l[:, 0]

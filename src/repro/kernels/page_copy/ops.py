@@ -42,11 +42,12 @@ def copy_pages(pages, src_ids, dst_ids, *, interpret: bool | None = None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def append_tokens(k_pages, v_pages, k_tok, v_tok, page_ids, offsets, *,
+def append_tokens(pools, toks, page_ids, offsets, *,
                   interpret: bool | None = None):
-    """Append one new token's K/V per sequence into its (exclusively
-    owned, pairwise-distinct) append page, all B sequences and all L
-    layers in one aliased call: k/v_tok (L, B, KV, Dh); page_ids,
-    offsets (B,). Returns the updated (k_pages, v_pages)."""
-    return token_append_kernel(k_pages, v_pages, k_tok, v_tok,
-                               page_ids, offsets, interpret=interpret)
+    """Append one new token's row per sequence into its (exclusively
+    owned, pairwise-distinct) append page, in every pool, all B sequences
+    and all L layers in one aliased call: pools, a tuple of (L, P, page,
+    *row); toks, one (L, B, *row) per pool; page_ids, offsets (B,).
+    Returns the updated pools."""
+    return token_append_kernel(tuple(pools), tuple(toks), page_ids, offsets,
+                               interpret=interpret)

@@ -5,12 +5,12 @@ import jax.numpy as jnp
 
 
 def page_gather_ref(pages, page_ids) -> jnp.ndarray:
-    """pages (L, P, page, KV, Dh); page_ids (n,) → (L, n, page, KV, Dh)."""
+    """pages (L, P, page, *row); page_ids (n,) → (L, n, page, *row)."""
     return pages[:, page_ids]
 
 
 def page_scatter_ref(pages, staging, page_ids) -> jnp.ndarray:
-    """pages (L, P, page, KV, Dh); staging (L, n, page, KV, Dh);
+    """pages (L, P, page, *row); staging (L, n, page, *row);
     page_ids (n,) → pages with rows page_ids replaced by staging."""
     return pages.at[:, page_ids].set(staging)
 
@@ -20,10 +20,9 @@ def copy_pages_ref(pages, src_ids, dst_ids) -> jnp.ndarray:
     return pages.at[:, dst_ids].set(pages[:, src_ids])
 
 
-def append_tokens_ref(k_pages, v_pages, k_tok, v_tok, page_ids, offsets):
-    """k/v_pages (L, P, page, KV, Dh); k/v_tok (L, B, KV, Dh);
-    page_ids/offsets (B,) → pools with
-    pages[:, page_ids[b], offsets[b]] = tok[:, b]."""
-    k_pages = k_pages.at[:, page_ids, offsets].set(k_tok.astype(k_pages.dtype))
-    v_pages = v_pages.at[:, page_ids, offsets].set(v_tok.astype(v_pages.dtype))
-    return k_pages, v_pages
+def append_tokens_ref(pools, toks, page_ids, offsets):
+    """pools, a tuple of (L, P, page, *row); toks, one (L, B, *row) per
+    pool; page_ids/offsets (B,) → pools with
+    pool[:, page_ids[b], offsets[b]] = tok[:, b]."""
+    return tuple(p.at[:, page_ids, offsets].set(t.astype(p.dtype))
+                 for p, t in zip(pools, toks))

@@ -14,6 +14,17 @@ Three execution paths, all static-shape (TPU/XLA friendly):
   in the dry-run shapes, so dense == exact).
 
 Layouts: q (B, S, H, Dh); k/v (B, S, KV, Dh); caches (B, S_max, KV, Dh).
+The value head size may differ from the query/key one (latent
+attention: keys 192 wide, values 128).
+
+Multi-head latent attention (DeepSeek-V2/V3, ``cfg.mla``): the cache
+holds one row per token, ``[RMSNorm(c), rope(k_pe)]`` (``kv_lora_rank``
++ ``qk_rope_head_dim`` values, shared by all heads, zero-padded to
+``cache_width``); prefill and extend
+expand it to per-head keys and values through ``wkb``/``wvb``
+(``mla_expand``) and attend as above. The paged decode absorbs
+``wkb`` into the query and ``wvb`` into the output instead
+(``serving/paged_runtime.py``).
 """
 from __future__ import annotations
 
@@ -56,6 +67,64 @@ def attention_specs(cfg: ModelConfig, dtype: str) -> dict:
     return specs
 
 
+def mla_specs(cfg: ModelConfig, dtype: str) -> dict:
+    """Latent attention without a query low-rank (``q_lora_rank`` 0):
+    q = x wq; [c, k_pe] = x wkv_a, c normalised by ``kv_norm``; per head
+    k_nope = c wkb, v = c wvb; o = attention wo."""
+    a, D, H = cfg.mla, cfg.d_model, cfg.num_heads
+    assert a.q_lora_rank == 0, "a query low-rank projection is not built"
+    R, Dn, Dr, Dv = (a.kv_lora_rank, a.qk_nope_head_dim, a.qk_rope_head_dim,
+                     a.v_head_dim)
+    return {
+        "wq": ParamSpec((D, H, Dn + Dr), ("embed", "q_heads", "head_dim"),
+                        dtype=dtype, fan_in=D),
+        "wkv_a": ParamSpec((D, R + Dr), ("embed", "kv_latent"), dtype=dtype),
+        "kv_norm": ParamSpec((R,), ("kv_latent",), init="zeros", dtype=dtype),
+        "wkb": ParamSpec((R, H, Dn), ("kv_latent", "q_heads", "head_dim"),
+                         dtype=dtype, fan_in=R),
+        "wvb": ParamSpec((R, H, Dv), ("kv_latent", "q_heads", "head_dim"),
+                         dtype=dtype, fan_in=R),
+        "wo": ParamSpec((H, Dv, D), ("q_heads", "head_dim", "embed"),
+                        dtype=dtype, fan_in=H * Dv),
+    }
+
+
+def mla_query(p: dict, x: jax.Array, cfg: ModelConfig,
+              positions: jax.Array) -> jax.Array:
+    """x (B,S,D) -> q (B,S,H,Dn+Dr), rotary embedding on the trailing Dr."""
+    Dn, Dr = cfg.mla.qk_nope_head_dim, cfg.mla.qk_rope_head_dim
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    cos, sin = rope_tables(positions, Dr, cfg.rope_theta)
+    return jnp.concatenate([q[..., :Dn], apply_rope(q[..., Dn:], cos, sin)],
+                           axis=-1)
+
+
+def mla_latent(p: dict, x: jax.Array, cfg: ModelConfig,
+               positions: jax.Array) -> jax.Array:
+    """x (B,S,D) -> the cache rows (B,S,W): [RMSNorm(c), rope(k_pe)],
+    zero-padded to the stored width W (``MLAConfig.cache_width``)."""
+    a = cfg.mla
+    R, Dr = a.kv_lora_rank, a.qk_rope_head_dim
+    kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
+    c = rms_norm(kv[..., :R], p["kv_norm"], cfg.norm_eps)
+    cos, sin = rope_tables(positions, Dr, cfg.rope_theta)
+    k_pe = apply_rope(kv[..., None, R:], cos, sin)[..., 0, :]
+    pad = jnp.zeros(c.shape[:-1] + (a.cache_width - a.cache_dim,), c.dtype)
+    return jnp.concatenate([c, k_pe, pad], axis=-1)
+
+
+def mla_expand(p: dict, lat: jax.Array, cfg: ModelConfig
+               ) -> tuple[jax.Array, jax.Array]:
+    """Cache rows (B,T,W) -> per-head keys (B,T,H,Dn+Dr) (the rotary
+    part shared by every head) and values (B,T,H,Dv)."""
+    R, Dr = cfg.mla.kv_lora_rank, cfg.mla.qk_rope_head_dim
+    c, k_pe = lat[..., :R], lat[..., R:R + Dr]
+    k_nope = jnp.einsum("btr,rhk->bthk", c, p["wkb"])
+    v = jnp.einsum("btr,rhk->bthk", c, p["wvb"])
+    k_pe = jnp.broadcast_to(k_pe[:, :, None], k_nope.shape[:3] + k_pe.shape[-1:])
+    return jnp.concatenate([k_nope, k_pe], axis=-1), v
+
+
 def qkv_project(p: dict, x: jax.Array, cfg: ModelConfig,
                 positions: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     """x (B,S,D) -> q (B,S,H,Dh), k/v (B,S,KV,Dh) with RoPE applied."""
@@ -86,7 +155,7 @@ def _block_attn(q, k, v, mask, scale, cap):
 
     Returns (out (B,Q,H,Dh), m (B,KV,G,Q), l (B,KV,G,Q)) fp32 stats."""
     B, Q, H, Dh = q.shape
-    KV = k.shape[2]
+    KV, Dv = k.shape[2], v.shape[-1]
     G = H // KV
     if k.dtype != q.dtype:          # fp8 KV cache: upcast at the MXU edge
         k = k.astype(q.dtype)
@@ -101,7 +170,7 @@ def _block_attn(q, k, v, mask, scale, cap):
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)                     # (B,KV,G,Q)
     o = jnp.einsum("bkgqt,btkd->bqkgd", p.astype(v.dtype), v)
-    return o.reshape(B, Q, H, Dh), m, l
+    return o.reshape(B, Q, H, Dv), m, l
 
 
 def _combine(acc_o, acc_m, acc_l, o, m, l):
@@ -139,7 +208,7 @@ def attend_causal(q, k, v, *, scale: float, cap: float = 0.0,
     """Causal attention over the full sequence. kv_len: optional (B,) valid
     lengths for padded batches (keys at pos >= kv_len are masked)."""
     B, S, H, Dh = q.shape
-    KV = k.shape[2]
+    KV, Dv = k.shape[2], v.shape[-1]
     q_chunk = min(q_chunk, S)
     kv_chunk = min(kv_chunk, S)
     # pad S to multiples
@@ -158,7 +227,7 @@ def attend_causal(q, k, v, *, scale: float, cap: float = 0.0,
     # per step instead of dynamic-slicing a (possibly resharded) full K
     # inside the loop body (XLA otherwise re-gathers full K per block).
     ks = k.reshape(B, nk, kv_chunk, KV, Dh).transpose(1, 0, 2, 3, 4)
-    vs = v.reshape(B, nk, kv_chunk, KV, Dh).transpose(1, 0, 2, 3, 4)
+    vs = v.reshape(B, nk, kv_chunk, KV, Dv).transpose(1, 0, 2, 3, 4)
 
     def q_body(_, qi_blk):
         qi, q_blk = qi_blk
@@ -182,14 +251,14 @@ def attend_causal(q, k, v, *, scale: float, cap: float = 0.0,
                                  lambda c: c, (acc_o, acc_m, acc_l))
             return carry, None
 
-        init = (jnp.zeros((B, q_chunk, H, Dh), q.dtype),
+        init = (jnp.zeros((B, q_chunk, H, Dv), q.dtype),
                 jnp.full((B, KV, H // KV, q_chunk), NEG_INF, jnp.float32),
                 jnp.zeros((B, KV, H // KV, q_chunk), jnp.float32))
         (o, m, l), _ = jax.lax.scan(kv_body, init, (jnp.arange(nk), ks, vs))
         return None, _finalize(o, m, l)
 
     _, outs = jax.lax.scan(q_body, None, (jnp.arange(nq), qs))
-    out = outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, Dh)
+    out = outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, Dv)
     return out[:, :S]
 
 
@@ -241,28 +310,16 @@ def write_cache(cache_k, cache_v, k_new, v_new, cache_len, *, ring: bool = False
     alias the donated cache buffer). Ring caches (sliding-window layers,
     capacity == window) use modulo scatter."""
     W = cache_k.shape[1]
-    C = k_new.shape[1]
     k_new = k_new.astype(cache_k.dtype)   # fp8 KV cache: quantize on write
     v_new = v_new.astype(cache_v.dtype)
 
     if not ring:
-        if jnp.ndim(cache_len) == 0:
-            start = jnp.minimum(jnp.asarray(cache_len, jnp.int32), W - C)
-            ck = jax.lax.dynamic_update_slice_in_dim(cache_k, k_new, start, 1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache_v, v_new, start, 1)
-            return ck, cv
-
-        def one_dus(ck, cv, kn, vn, ln):
-            s = jnp.minimum(ln, W - C)
-            return (jax.lax.dynamic_update_slice_in_dim(ck, kn, s, 0),
-                    jax.lax.dynamic_update_slice_in_dim(cv, vn, s, 0))
-
-        return jax.vmap(one_dus)(cache_k, cache_v, k_new, v_new,
-                                 cache_len.astype(jnp.int32))
+        return (write_rows(cache_k, k_new, cache_len),
+                write_rows(cache_v, v_new, cache_len))
 
     if jnp.ndim(cache_len) == 0:
         start = jnp.asarray(cache_len, jnp.int32) % W
-        idx = (start + jnp.arange(C)) % W  # wraps; later writes win
+        idx = (start + jnp.arange(k_new.shape[1])) % W  # wraps; later writes win
         ck = cache_k.at[:, idx].set(k_new)
         cv = cache_v.at[:, idx].set(v_new)
         return ck, cv
@@ -273,6 +330,20 @@ def write_cache(cache_k, cache_v, k_new, v_new, cache_len, *, ring: bool = False
 
     ck, cv = jax.vmap(one)(cache_k, cache_v, k_new, v_new, cache_len.astype(jnp.int32))
     return ck, cv
+
+
+def write_rows(cache, new, cache_len):
+    """Write rows ``new`` (B,C,...) into ``cache`` (B,W,...) at offsets
+    ``cache_len`` (B,) or scalar, by dynamic_update_slice (in place on a
+    donated buffer)."""
+    W, C = cache.shape[1], new.shape[1]
+    new = new.astype(cache.dtype)
+    if jnp.ndim(cache_len) == 0:
+        start = jnp.minimum(jnp.asarray(cache_len, jnp.int32), W - C)
+        return jax.lax.dynamic_update_slice_in_dim(cache, new, start, 1)
+    return jax.vmap(lambda c, n, ln: jax.lax.dynamic_update_slice_in_dim(
+        c, n, jnp.minimum(ln, W - C), 0))(cache, new,
+                                          cache_len.astype(jnp.int32))
 
 
 def attend_decode(q, cache_k, cache_v, kv_len, *, scale: float,

@@ -1,7 +1,18 @@
-"""Mixture-of-Experts FFN with static-capacity (GShard-style) dispatch.
+"""Mixture-of-Experts FFN: a dropless layer for the served path, and
+static-capacity (GShard-style) dispatch under a device mesh.
 
-Why static capacity: the dry-run must lower with static shapes, and the HLO
-FLOP count must reflect *active* compute (top_k × capacity_factor), not
+**Dropless** (``moe_dropless``; every single-device forward, so prefill,
+extend and the paged decode): the router scores all experts, each token
+keeps its top-k, and the copies routed to the experts this chip holds
+(``cfg.moe.held``) are sorted by expert and run through grouped matmuls
+(``jax.lax.ragged_dot``). There is no capacity: a token's output depends
+on that token alone, so a decode row is the same in any batch. Copies
+routed to experts held elsewhere add nothing here, which is this chip's
+share of an expert-parallel layer with the exchange left out.
+
+**Static capacity** (``moe_apply`` under a mesh: sharded training and the
+dry-run). Why static capacity: the dry-run must lower with static shapes,
+and the HLO FLOP count must reflect *active* compute (top_k × capacity_factor), not
 all-experts-dense. One-hot dispatch einsums are avoided (they cost
 O(T² · top_k · D) — quadratic in tokens); instead we compute each token-copy's
 slot with a cumsum over a (T·top_k, E) one-hot int8 matrix (cheap, int ops)
@@ -26,15 +37,22 @@ from repro.models.mlp import mlp_apply, mlp_specs
 
 
 def moe_specs(cfg: ModelConfig, dtype: str) -> dict:
+    """The router over every expert (and its correction bias, with
+    sigmoid scoring), the held experts' weights, the shared experts as
+    one MLP of their summed width."""
     m = cfg.moe
-    D, F, E = cfg.d_model, m.d_ff_expert, m.num_experts
+    D, F, E, Eh = cfg.d_model, m.d_ff_expert, m.num_experts, m.n_held
     ex_axis = "experts"
     specs = {
         "router": ParamSpec((D, E), ("embed", "experts_router"), dtype="float32", keep_dtype=True),
-        "w1": ParamSpec((E, D, F), (ex_axis, "embed", "moe_mlp"), dtype=dtype),
-        "w3": ParamSpec((E, D, F), (ex_axis, "embed", "moe_mlp"), dtype=dtype),
-        "w2": ParamSpec((E, F, D), (ex_axis, "moe_mlp", "embed"), dtype=dtype),
+        "w1": ParamSpec((Eh, D, F), (ex_axis, "embed", "moe_mlp"), dtype=dtype),
+        "w3": ParamSpec((Eh, D, F), (ex_axis, "embed", "moe_mlp"), dtype=dtype),
+        "w2": ParamSpec((Eh, F, D), (ex_axis, "moe_mlp", "embed"), dtype=dtype),
     }
+    if m.scoring == "sigmoid":
+        specs["router_bias"] = ParamSpec((E,), ("experts_router",),
+                                         init="zeros", dtype="float32",
+                                         keep_dtype=True)
     if m.num_shared_experts:
         specs["shared"] = mlp_specs(D, F * m.num_shared_experts, dtype)
     return specs
@@ -47,13 +65,57 @@ def _capacity(tokens: int, cfg: ModelConfig) -> int:
 
 
 def route(p: dict, x: jax.Array, cfg: ModelConfig):
-    """x (T, D) -> (probs (T, k), expert_ids (T, k)) with softmax-over-topk
-    normalization (Qwen3/Mixtral convention)."""
+    """x (T, D) -> (weights (T, k) float32, expert_ids (T, k)), scored in
+    float32 over every expert. ``softmax``: softmax over the top-k logits
+    (Qwen3/Mixtral). ``sigmoid`` (DeepSeek-V3 ``noaux_tc``, one group):
+    the top-k of sigmoid score + correction bias, weighted by the scores
+    without the bias, normalised over the k chosen (``norm_topk``); both
+    then scaled by ``routed_scaling``."""
     m = cfg.moe
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), p["router"])
-    top_logits, ids = jax.lax.top_k(logits, m.top_k)
-    probs = jax.nn.softmax(top_logits, axis=-1)
-    return probs, ids
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    if m.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(scores + p["router_bias"], m.top_k)
+        w = jnp.take_along_axis(scores, ids, axis=-1)
+        if m.norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    else:
+        top_logits, ids = jax.lax.top_k(logits, m.top_k)
+        w = jax.nn.softmax(top_logits, axis=-1)
+    return w * m.routed_scaling, ids
+
+
+def moe_dropless(p: dict, x: jax.Array, cfg: ModelConfig
+                 ) -> tuple[jax.Array, jax.Array]:
+    """x (..., D) -> (y (..., D), the token-copies routed to a held expert
+    (int32)). Each copy to a held expert is computed; the rest add
+    nothing. The held copies are sorted by expert (the others last, past
+    the groups, where ``ragged_dot`` writes zeros) and run through the
+    held experts' grouped matmuls; each token sums its k weighted copies
+    in float32, then adds the shared experts."""
+    m = cfg.moe
+    D = x.shape[-1]
+    xt = x.reshape(-1, D)
+    T, k, Eh = xt.shape[0], m.top_k, m.n_held
+    w, ids = route(p, xt, cfg)
+    local = ids.reshape(-1) - m.held_start
+    held = (local >= 0) & (local < Eh)
+    key = jnp.where(held, local, Eh)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(Eh), axis=0, dtype=jnp.int32)
+    rows = jnp.take(xt, order // k, axis=0)
+    act = activation_fn(cfg.activation)
+    h = act(jax.lax.ragged_dot(rows, p["w1"], sizes)) \
+        * jax.lax.ragged_dot(rows, p["w3"], sizes)
+    y = jax.lax.ragged_dot(h.astype(rows.dtype), p["w2"], sizes)
+    y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, k, D)
+    wt = jnp.where(held.reshape(T, k), w, 0.0)
+    out = jnp.sum(y.astype(jnp.float32) * wt[..., None], axis=1)
+    out = out.astype(x.dtype).reshape(x.shape)
+    if m.num_shared_experts:
+        out = out + mlp_apply(p["shared"], x, cfg.activation)
+    return out, jnp.sum(held, dtype=jnp.int32)
 
 
 def _dispatch_plan(flat_ids: jax.Array, E: int, C: int):
@@ -107,11 +169,16 @@ MAX_GROUP_TOKENS = 8192  # sub-chunk groups beyond this (bounds dispatch bufs)
 
 
 def moe_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """x (B, S, D) -> (B, S, D). Grouped (per-data-shard) dispatch; groups
-    larger than MAX_GROUP_TOKENS are processed in scanned sub-chunks so the
-    (E*C, D) dispatch buffers stay bounded (32k-prefill would otherwise
+    """x (B, S, D) -> (B, S, D). On one device, the dropless layer. Under
+    a mesh: grouped (per-data-shard) dispatch; groups larger than
+    MAX_GROUP_TOKENS are processed in scanned sub-chunks so the (E*C, D)
+    dispatch buffers stay bounded (32k-prefill would otherwise
     materialize ~5 GB/layer)."""
+    from repro.dist.sharding import current_rules
     m = cfg.moe
+    if (current_rules() or {}).get("__mesh__") is None:
+        return moe_dropless(p, x, cfg)[0]
+    assert m.n_held == m.num_experts, "capacity dispatch holds every expert"
     B, S, D = x.shape
     T = B * S
     if m.sharding_mode == "ep_a2a":

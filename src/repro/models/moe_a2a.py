@@ -28,7 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.common import activation_fn
-from repro.models.moe import _capacity, _dispatch_plan
+from repro.models.moe import _capacity, _dispatch_plan, route
 
 
 def moe_apply_a2a(p: dict, x: jax.Array, cfg: ModelConfig, mesh,
@@ -44,15 +44,13 @@ def moe_apply_a2a(p: dict, x: jax.Array, cfg: ModelConfig, mesh,
     act = activation_fn(cfg.activation)
     cd = jnp.dtype(cfg.compute_dtype)
 
-    def shard_fn(xs, router_w, w1, w3, w2):
+    def shard_fn(xs, router, w1, w3, w2):
         # xs: (B_loc, S, D) tokens of this data shard (replicated over EP
         # axis); w*: (E_loc, ...) this EP shard's experts
         Bl = xs.shape[0]
         T = Bl * S
         xt = xs.reshape(T, D)
-        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), router_w)
-        probs_all, ids = jax.lax.top_k(logits, k)
-        probs = jax.nn.softmax(probs_all, axis=-1)
+        probs, ids = route(router, xt, cfg)
 
         # local slot plan against ALL experts; C_pair = this shard's
         # per-expert capacity (global per-expert capacity = n_ep * C_pair,
@@ -90,7 +88,8 @@ def moe_apply_a2a(p: dict, x: jax.Array, cfg: ModelConfig, mesh,
                            P(expert_axis)),
                  out_specs=batch_spec)
     fn = jax.shard_map(shard_fn, mesh=mesh, check_vma=False, **specs)
-    out = fn(x, p["router"], p["w1"].astype(cd), p["w3"].astype(cd),
+    router = {kk: p[kk] for kk in ("router", "router_bias") if kk in p}
+    out = fn(x, router, p["w1"].astype(cd), p["w3"].astype(cd),
              p["w2"].astype(cd))
     if m.num_shared_experts:
         from repro.models.mlp import mlp_apply

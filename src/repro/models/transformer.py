@@ -5,7 +5,9 @@ stack is built as *scan groups* so ``jax.lax.scan`` keeps HLO size and
 compile time O(1) in depth:
 
 - dense / moe / audio / vlm: scan over uniform layers (optionally a few
-  leading unstacked dense layers, Moonlight-style);
+  leading dense layers, Moonlight-style, kept in the same stacked cache:
+  cache layer i is model layer i); attention is grouped-query or, with
+  ``cfg.mla``, latent (one cache row of latent + rotary key per token);
 - gemma2: scan over (local, global) layer pairs;
 - rwkv6: scan over rwkv layers (time-mix + channel-mix);
 - zamba2: scan over groups of [shared-attn block (tied, alternating) +
@@ -89,8 +91,9 @@ class Model:
 
         # attention families (dense / moe / audio / vlm / gemma2)
         def attn_layer():
+            specs = attn.mla_specs if cfg.mla else attn.attention_specs
             l = {"ln1": _norm_spec(D, dt), "ln2": _norm_spec(D, dt),
-                 "attn": attn.attention_specs(cfg, dt)}
+                 "attn": specs(cfg, dt)}
             if cfg.sandwich_norm:
                 l["ln1_post"] = _norm_spec(D, dt)
                 l["ln2_post"] = _norm_spec(D, dt)
@@ -152,13 +155,15 @@ class Model:
                     "k_global": ((G, batch, max_len, KV, Dh), cd),
                     "v_global": ((G, batch, max_len, KV, Dh), cd)}
         L = cfg.num_layers
-        first_k = cfg.moe.first_k_dense if cfg.moe else 0
-        out = {"k": ((L - first_k, batch, max_len, KV, Dh), cd),
-               "v": ((L - first_k, batch, max_len, KV, Dh), cd)}
-        if first_k:
-            out["k0"] = ((first_k, batch, max_len, KV, Dh), cd)
-            out["v0"] = ((first_k, batch, max_len, KV, Dh), cd)
-        return out
+        if cfg.mla:
+            return {"c": ((L, batch, max_len, cfg.mla.cache_width), cd)}
+        return {"k": ((L, batch, max_len, KV, Dh), cd),
+                "v": ((L, batch, max_len, KV, Dh), cd)}
+
+    def cache_keys(self) -> tuple[str, ...]:
+        """The uniform-attention cache's leaves, in pool order: ``("k",
+        "v")``, or ``("c",)`` for latent attention."""
+        return ("c",) if self.cfg.mla else ("k", "v")
 
     def init_cache(self, batch: int, max_len: int):
         return jax.tree.map(lambda sd: jnp.zeros(sd[0], jnp.dtype(sd[1])),
@@ -185,23 +190,25 @@ class Model:
         if cfg.local_global_alternating:
             return {"k_local": kv_axes, "v_local": kv_axes,
                     "k_global": kv_axes, "v_global": kv_axes}
-        out = {"k": kv_axes, "v": kv_axes}
-        if cfg.moe and cfg.moe.first_k_dense:
-            out["k0"] = kv_axes
-            out["v0"] = kv_axes
-        return out
+        if cfg.mla:
+            return {"c": ("layers", "act_batch", "cache_seq", "kv_latent")}
+        return {"k": kv_axes, "v": kv_axes}
 
     # ---------------------------------------------------------------- layers
     def _attn_apply(self, p, x, kv, cache_len, mode, *, window=0):
-        """One attention sublayer. kv: (cache_k, cache_v) or None (train)."""
+        """One attention sublayer. kv: the layer's cache leaves in
+        ``cache_keys`` order — (cache_k, cache_v), or (latent,) — or None
+        (train)."""
         cfg = self.cfg
         B, S, D = x.shape
-        scale = 1.0 / math.sqrt(cfg.head_dim)
         if mode == "train" or kv is None:
             positions = jnp.arange(S)
         else:
             cl = jnp.asarray(cache_len)
             positions = (cl[..., None] if cl.ndim else cl) + jnp.arange(S)
+        if cfg.mla:
+            return self._mla_apply(p, x, kv, cache_len, mode, positions)
+        scale = 1.0 / math.sqrt(cfg.head_dim)
         q, k, v = attn.qkv_project(p, x, cfg, positions)
         # TP head padding (§Perf): when num_heads doesn't divide the model
         # axis, pad Q heads with zeros so the attention core shards instead
@@ -235,6 +242,23 @@ class Model:
         if o.shape[2] != H0:
             o = o[:, :, :H0]                       # drop TP padding heads
         return attn.out_project(p, o), new_kv
+
+    def _mla_apply(self, p, x, kv, cache_len, mode, positions):
+        """Latent attention: the layer's cache row is written, then
+        expanded to per-head keys and values (the chunk's own rows in
+        train/prefill, the whole cache in extend/decode)."""
+        cfg = self.cfg
+        scale = 1.0 / math.sqrt(cfg.mla.qk_head_dim)
+        q = attn.mla_query(p, x, cfg, positions)
+        lat = attn.mla_latent(p, x, cfg, positions)
+        new = None if kv is None else (attn.write_rows(kv[0], lat, cache_len),)
+        if mode in ("train", "prefill"):
+            k, v = attn.mla_expand(p, lat, cfg)
+            o = attn.attend_causal(q, k, v, scale=scale)
+        else:
+            k, v = attn.mla_expand(p, new[0].astype(x.dtype), cfg)
+            o = attn.attend_decode(q, k, v, cache_len, scale=scale)
+        return attn.out_project(p, o), new
 
     def _attn_layer(self, p, x, kv, cache_len, mode, window=0):
         cfg = self.cfg
@@ -336,11 +360,12 @@ class Model:
         new_cache = dict(cache) if cache is not None else None
 
         def scan_with_cache(layer_fn, blocks, cache_tree, n_layers,
-                            extra_xs=None):
+                            extra_xs=None, offset=0):
             """Scan over stacked layers. With a cache, the full stacked cache
             rides the CARRY and each layer slice is read/written with
             dynamic (update-)slice — XLA keeps the donated buffers in place
-            (xs/ys caches would force a second stacked copy)."""
+            (xs/ys caches would force a second stacked copy). Scanned layer
+            i uses cache layer ``offset + i``."""
             xs = (blocks, jnp.arange(n_layers)) if extra_xs is None \
                 else (blocks, jnp.arange(n_layers), extra_xs)
 
@@ -359,10 +384,10 @@ class Model:
                 xc, cstack = carry
                 p = layer_in[0]
                 gi = layer_in[1]
-                st = take_layer(cstack, gi)
+                st = take_layer(cstack, gi + offset)
                 xc, new_st = layer_fn(p, xc, st, gi)
                 xc = constrain(xc, "act_batch", "act_seq", "act_embed")
-                cstack = tree_dus(cstack, new_st, gi)
+                cstack = tree_dus(cstack, new_st, gi + offset)
                 return (xc, cstack), None
 
             (x2, new_c), _ = jax.lax.scan(maybe_remat(body), (x, cache_tree), xs)
@@ -443,30 +468,24 @@ class Model:
                 new_cache = sts
         else:
             first_k = cfg.moe.first_k_dense if cfg.moe else 0
-            if first_k:
-                for i in range(first_k):
-                    p0 = take_layer(params["dense_layers"], i)
-                    kv0 = None
-                    if cache is not None:
-                        kv0 = (cache["k0"][i], cache["v0"][i])
-                    x, new_kv0 = self._attn_layer(p0, x, kv0, cache_len, mode)
-                    if cache is not None:
-                        new_cache["k0"] = new_cache["k0"].at[i].set(new_kv0[0])
-                        new_cache["v0"] = new_cache["v0"].at[i].set(new_kv0[1])
+            keys = self.cache_keys()
 
             def layer_fn(p, xc, st, gi):
-                kv = None if st is None else (st["k"], st["v"])
+                kv = None if st is None else tuple(st[k] for k in keys)
                 xc, new_kv = self._attn_layer(p, xc, kv, cache_len, mode)
-                new_st = None if st is None else {"k": new_kv[0], "v": new_kv[1]}
-                return xc, new_st
+                return xc, None if st is None else dict(zip(keys, new_kv))
 
-            st = None
-            if cache is not None:
-                st = {"k": cache["k"], "v": cache["v"]}
+            st = None if cache is None else {k: cache[k] for k in keys}
+            for i in range(first_k):         # leading dense layers
+                x, new_st = layer_fn(take_layer(params["dense_layers"], i), x,
+                                     None if st is None else take_layer(st, i),
+                                     i)
+                if st is not None:
+                    st = tree_dus(st, new_st, i)
             x, sts = scan_with_cache(layer_fn, params["blocks"], st,
-                                     cfg.num_layers - first_k)
+                                     cfg.num_layers - first_k, offset=first_k)
             if cache is not None:
-                new_cache["k"], new_cache["v"] = sts["k"], sts["v"]
+                new_cache.update(sts)
 
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         return x, new_cache

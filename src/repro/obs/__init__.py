@@ -93,6 +93,14 @@ class Telemetry:
             "continuum_page_cow_splits_total",
             "Copy-on-write page splits in the paged KV runtime",
             ("replica",))
+        self.moe_held_copies = m.counter(
+            "continuum_moe_held_copies_total",
+            "Decode token-copies routed to an expert this replica holds, "
+            "summed over MoE layers", ("replica",))
+        self.moe_copies = m.counter(
+            "continuum_moe_copies_total",
+            "Decode token-copies routed to any expert, summed over MoE "
+            "layers", ("replica",))
         self.step_seconds = m.histogram(
             "continuum_step_seconds", "Engine step duration (virtual s)",
             ("replica",))

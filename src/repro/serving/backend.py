@@ -94,7 +94,7 @@ def _watch_compiles() -> None:
 def kv_pool_budget(cfg: ModelConfig, *, limit: float, held_bytes: float,
                    max_len: int, chunk: int, max_batch: int,
                    extra_pages: int, page_size: int) -> float:
-    """Bytes of KV pool P (k and v, one copy) that fit in ``limit``
+    """Bytes of KV pool P (every pool, one copy) that fit in ``limit``
     device bytes beside everything else the served path holds. With W
     the weights as held (``held_bytes``: their real dtype) and C their
     compute-dtype copy, which every step casts:
@@ -111,8 +111,7 @@ def kv_pool_budget(cfg: ModelConfig, *, limit: float, held_bytes: float,
     kv_item = jnp.dtype(cfg.kv_cache_dtype or cfg.compute_dtype).itemsize
     cast = cfg.param_count() * jnp.dtype(cfg.compute_dtype).itemsize
     T = _pow2(max_len)                                 # scratch bucket cap
-    kv_per_pos = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim \
-        * kv_item                                      # k + v
+    kv_per_pos = cfg.kv_bytes_per_token(kv_item)       # every pool
     scratch = 2 * T * kv_per_pos                       # in + out
     scores = 2 * min(chunk, T) * T * cfg.num_heads * 4  # f32 s and p
     logits = 2 * max_batch * cfg.vocab_size * 4
@@ -120,6 +119,12 @@ def kv_pool_budget(cfg: ModelConfig, *, limit: float, held_bytes: float,
     pool = min((free - max(cast + logits, scratch)) / 2,
                free - cast - scratch - scores)
     return max(pool - extra_pages * page_size * kv_per_pos, 0.0)
+
+
+def _real_bytes(bufs, pages: int) -> int:
+    """Bytes of ``pages`` real pages across staged buffers (L, W, page,
+    *row), W padded slots each."""
+    return sum(b.nbytes for b in bufs) // bufs[0].shape[1] * pages
 
 
 def _padded(nbytes: int, pages: int) -> int:
@@ -157,8 +162,9 @@ class JaxModelBackend:
         self.max_len = max_len
         self._rng = rng
         self._streams: dict[str, np.ndarray] = {}  # stream -> token ids
-        # staged-out host copies: program_id -> (np k, np v, tokens); the
-        # buffers are the page_copy gather's (L, pow2(pages), page, KV, Dh)
+        # staged-out host copies: program_id -> (one np buffer per pool,
+        # ..., tokens); the buffers are the page_copy gather's (L,
+        # pow2(pages), page, *row)
         self.host_caches: dict[str, tuple] = {}
         # page-stamped radix mirror of the scheduler's accounting index
         # (enable_prefix_sharing); None = no cross-program sharing
@@ -171,9 +177,9 @@ class JaxModelBackend:
         # restores that kept fewer pages than were staged (the store's
         # usable prefix shrank): the device-side re-pad drops the rest
         self.restores_truncated = 0
-        # tier moves: KV bytes of the programs' real pages (k + v) and the
-        # host wall of each move, stage-out through evict, restore through
-        # the scatter dispatch (``kv.stage_out``/``kv.restore`` spans)
+        # tier moves: KV bytes of the programs' real pages (every pool)
+        # and the host wall of each move, stage-out through evict, restore
+        # through the scatter dispatch (``kv.stage_out``/``kv.restore``)
         self.stage_out_bytes = 0
         self.restore_bytes = 0
         self.stage_out_seconds = 0.0
@@ -291,10 +297,10 @@ class JaxModelBackend:
         t0 = time.perf_counter()
         with span("kv.stage_out", program=program_id,
                   pages=len(e.pages)) as sp:
-            k, v, n = rt.stage_out(program_id)
-            self.host_caches[program_id] = (k, v, n)
+            staged = rt.stage_out(program_id)
+            self.host_caches[program_id] = staged
             rt.evict(program_id, force=True)
-            nbytes = (k.nbytes + v.nbytes) // k.shape[1] * len(e.pages)
+            nbytes = _real_bytes(staged[:-1], len(e.pages))
             if sp is not None:
                 sp.set_metadata(bytes=nbytes, padded_bytes=_padded(
                     nbytes, len(e.pages)))
@@ -329,28 +335,42 @@ class JaxModelBackend:
         entry = self.host_caches.pop(program_id, None)
         if entry is None:
             return                       # lost copy: engine recomputes
-        k, v, staged = entry
+        *bufs, staged = entry
         n = staged if tokens is None else min(staged, int(tokens))
         if n <= 0:
             return
         ps = self.runtime.page_size
         pages = math.ceil(n / ps)
         staged_pages = math.ceil(staged / ps)
-        nbytes = (k.nbytes + v.nbytes) // k.shape[1] * pages
+        nbytes = _real_bytes(bufs, pages)
         t0 = time.perf_counter()
         with span("kv.restore", program=program_id, pages=pages,
                   staged_pages=staged_pages, bytes=nbytes,
                   padded_bytes=_padded(nbytes, pages), priced_s=priced_s):
-            ids = self.runtime.restore(program_id, k, v, n)
+            ids = self.runtime.restore(program_id, *bufs, n)
         self._note_move("h2d", nbytes, time.perf_counter() - t0)
         if pages < staged_pages:
             self.restores_truncated += 1
         if self.verify_staging:          # differential harness: bit-exact?
-            back_k, back_v = self.runtime.read_pages(ids)
-            ok = bool(np.array_equal(back_k[:, :pages], k[:, :pages])) \
-                and bool(np.array_equal(back_v[:, :pages], v[:, :pages]))
+            back = self.runtime.read_pages(ids)
+            ok = all(np.array_equal(b[:, :pages], s[:, :pages])
+                     for b, s in zip(back, bufs))
             self.staging_checks.append((program_id, ok))
         self.restores += 1
+
+    def _note_copies(self, sp, held: int, copies: int) -> None:
+        """The decode step's expert token-copies, read after the step's
+        sync: as ``model.sync`` arguments while a profile records, and on
+        the registry when telemetry is attached."""
+        if sp is not None:
+            sp.set_metadata(held_copies=held, copies=copies,
+                            moe_layers=self.cfg.num_layers
+                            - self.cfg.moe.first_k_dense)
+        obs = self.runtime.obs
+        if obs is not None:
+            key = (self.runtime.obs_replica,)
+            obs.moe_held_copies.inc(float(held), key)
+            obs.moe_copies.inc(float(copies), key)
 
     # ------------------------------------------------------------ execute
     def _req_hashes(self, req):
@@ -430,9 +450,12 @@ class JaxModelBackend:
             self.decode_tokens_computed += len(decode_pids)
         # the step ends when the device is done: the pools are the last
         # thing every step writes, the next tokens the last it reads
-        with span("model.sync"):
-            jax.block_until_ready((rt.k_pages, rt.v_pages,
+        with span("model.sync") as sp:
+            jax.block_until_ready((rt.pools,
                                    [rt._last[p] for p in decode_pids]))
+            if decode_pids and rt.moe_copies is not None and \
+                    (sp is not None or rt.obs is not None):
+                self._note_copies(sp, *np.asarray(rt.moe_copies).tolist())
         dt = time.perf_counter() - t0 - (compile_stats()["seconds"] - c0)
         dt = max(dt, 1e-6)
         self.step_seconds.append(dt)

@@ -19,9 +19,14 @@ staging buffers (one bulk DMA) for tier moves through the
 same buffers.
 
 Works for the uniform-attention families (dense/moe/audio/vlm). The
+model names the pools (``Model.cache_keys``): ``k_pages`` and ``v_pages``
+(L, P, page, KV, Dh) for grouped-query attention, one ``c_pages`` (L, P,
+page, W) for latent attention, whose row is the latent and the rotary
+key (``MLAConfig.cache_width``); pool layer i is model layer i. Every
+path below handles the pools as a tuple in that order. The
 engine-level BlockManager does the accounting; this runtime holds the
-actual arrays (on TPU: HBM pools consumed by the kernel's scalar-prefetch
-block tables; on CPU: interpret mode).
+actual arrays (on TPU: HBM pools consumed by the kernel's
+scalar-prefetch block tables; on CPU: interpret mode).
 """
 from __future__ import annotations
 
@@ -36,12 +41,14 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels import resolve_interpret
-from repro.kernels.decode_attention import paged_decode_attention
+from repro.kernels.decode_attention import (paged_decode_attention,
+                                            paged_mla_decode)
 from repro.kernels.page_copy import (append_tokens, copy_pages, gather_pages,
                                      scatter_pages)
 from repro.models import attention as attn_mod
-from repro.models.common import cast_params, rms_norm
+from repro.models.common import cast_params, rms_norm, take_layer
 from repro.models.mlp import mlp_apply
+from repro.models.moe import moe_dropless
 from repro.models.transformer import Model
 from repro.obs.spans import span
 
@@ -64,50 +71,51 @@ def _pad_ids(ids: list[int], n: int, *rest: list[int]) -> list[np.ndarray]:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _gather_span(cache_k, cache_v, k_pages, v_pages, ids, *, interpret):
+def _gather_span(caches, pools, ids, *, interpret):
     """Pages ``ids`` (n,) -> scratch positions [0, n*page) of the
-    contiguous (L, 1, T, KV, Dh) caches, through the page_copy gather."""
+    contiguous (L, 1, T, *row) caches, one per pool, through the page_copy
+    gather."""
     def one(cache, pages):
-        L, _, page, KV, Dh = pages.shape
+        L, _, page = pages.shape[:3]
         g = gather_pages(pages, ids, interpret=interpret)
-        g = g.reshape(L, 1, ids.shape[0] * page, KV, Dh).astype(cache.dtype)
-        return jax.lax.dynamic_update_slice_in_dim(cache, g, 0, axis=2)
-    return one(cache_k, k_pages), one(cache_v, v_pages)
+        g = g.reshape((L, 1, ids.shape[0] * page) + pages.shape[3:])
+        return jax.lax.dynamic_update_slice_in_dim(
+            cache, g.astype(cache.dtype), 0, axis=2)
+    return tuple(map(one, caches, pools))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _scatter_span(k_pages, v_pages, cache_k, cache_v, ids, blocks, lo, hi, *,
-                  interpret):
+def _scatter_span(pools, caches, ids, blocks, lo, hi, *, interpret):
     """Scratch positions [lo, hi) -> physical pages: ``ids`` (n,) hold the
     program's logical blocks ``blocks`` (n,). Positions of those pages
     outside [lo, hi) keep the pool's bytes (gathered, merged, scattered
     back through the page_copy kernels)."""
-    page = k_pages.shape[2]
+    page = pools[0].shape[2]
     pos = blocks[:, None] * page + jnp.arange(page)          # (n, page)
-    live = ((pos >= lo) & (pos < hi))[None, :, :, None, None]
-    src = jnp.clip(pos, 0, cache_k.shape[2] - 1)
+    src = jnp.clip(pos, 0, caches[0].shape[2] - 1)
 
     def one(pages, cache):
-        new = cache[:, 0][:, src].astype(pages.dtype)    # (L, n, page, KV, Dh)
+        live = ((pos >= lo) & (pos < hi)).reshape(
+            (1,) + pos.shape + (1,) * (pages.ndim - 3))
+        new = cache[:, 0][:, src].astype(pages.dtype)    # (L, n, page, *row)
         old = gather_pages(pages, ids, interpret=interpret)
         return scatter_pages(pages, jnp.where(live, new, old), ids,
                              interpret=interpret)
-    return one(k_pages, cache_k), one(v_pages, cache_v)
+    return tuple(map(one, pools, caches))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _restore_pages(k_pages, v_pages, k_staged, v_staged, ids, n, *,
-                   interpret):
-    """Staged (L, W, page, KV, Dh) buffers -> physical pages ``ids`` (W,),
-    padded by repeating the last of the n kept pages: slot i scatters
-    staged page min(i, n - 1), so a padded slot redoes the last kept
-    page's copy and never carries a page past the kept prefix."""
+def _restore_pages(pools, staged, ids, n, *, interpret):
+    """Staged (L, W, page, *row) buffers, one per pool -> physical pages
+    ``ids`` (W,), padded by repeating the last of the n kept pages: slot
+    i scatters staged page min(i, n - 1), so a padded slot redoes the
+    last kept page's copy and never carries a page past the kept
+    prefix."""
     take = jnp.minimum(jnp.arange(ids.shape[0]), n - 1)
 
-    def one(pages, staged):
-        return scatter_pages(pages, staged[:, take], ids,
-                             interpret=interpret)
-    return one(k_pages, k_staged), one(v_pages, v_staged)
+    def one(pages, buf):
+        return scatter_pages(pages, buf[:, take], ids, interpret=interpret)
+    return tuple(map(one, pools, staged))
 
 
 @dataclasses.dataclass
@@ -131,10 +139,8 @@ class PagedKVRuntime:
         self._decode_step = jax.jit(self._decode_step_impl)
         # one jitted prefill forward per (chunk bucket, scratch bucket, mode)
         self._forward = jax.jit(self.model.forward, static_argnames=("mode",))
-        L, KV, Dh = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        dt = jnp.dtype(cfg.kv_cache_dtype or cfg.compute_dtype)
-        self.k_pages = jnp.zeros((L, n_pages, page_size, KV, Dh), dt)
-        self.v_pages = jnp.zeros((L, n_pages, page_size, KV, Dh), dt)
+        self.cache_keys = self.model.cache_keys()
+        self._set_pools(self._zero_pools(n_pages))
         self.free: list[int] = list(range(n_pages))
         self.refs: dict[int, int] = {}             # page id -> holders
         self.programs: dict[str, ProgramEntry] = {}
@@ -153,6 +159,27 @@ class PagedKVRuntime:
         # through the same handle
         self.obs = None
         self.obs_replica = ""
+        # the last decode step's expert copies, int32 [routed to a held
+        # expert, all], summed over its MoE layers (None: no MoE)
+        self.moe_copies = None
+
+    # ------------------------------------------------------------- pools
+    @property
+    def pools(self) -> tuple:
+        """The physical pools, in ``cache_keys`` order."""
+        return tuple(getattr(self, f"{k}_pages") for k in self.cache_keys)
+
+    def _set_pools(self, pools) -> None:
+        for k, p in zip(self.cache_keys, pools):
+            setattr(self, f"{k}_pages", p)
+
+    def _zero_pools(self, n_pages: int) -> tuple:
+        """Empty pools of ``n_pages`` pages: each cache leaf's (L, 1, T,
+        *row) as (L, n_pages, page, *row)."""
+        shapes = self.model.cache_shapes(1, self.page_size)
+        return tuple(jnp.zeros((shapes[k][0][0], n_pages)
+                               + shapes[k][0][2:], jnp.dtype(shapes[k][1]))
+                     for k in self.cache_keys)
 
     # ------------------------------------------------------------- alloc
     def _alloc_page(self) -> int:
@@ -187,11 +214,8 @@ class PagedKVRuntime:
         if n_pages_total <= self.n_pages:
             return
         assert not self.refs, "grow() is a wiring-time call: pages are held"
-        shape = (self.k_pages.shape[0], n_pages_total) + self.k_pages.shape[2:]
-        dt = self.k_pages.dtype
-        self.k_pages = self.v_pages = None
-        self.k_pages = jnp.zeros(shape, dt)
-        self.v_pages = jnp.zeros(shape, dt)
+        self._set_pools((None,) * len(self.cache_keys))
+        self._set_pools(self._zero_pools(n_pages_total))
         self.free.extend(range(self.n_pages, n_pages_total))
         self.n_pages = n_pages_total
 
@@ -206,16 +230,12 @@ class PagedKVRuntime:
             new = self._alloc_page()
             src = jnp.asarray([pi], jnp.int32)
             dst = jnp.asarray([new], jnp.int32)
-            self.k_pages = copy_pages(self.k_pages, src, dst,
-                                      interpret=self.interpret)
-            self.v_pages = copy_pages(self.v_pages, src, dst,
-                                      interpret=self.interpret)
+            self._set_pools(copy_pages(p, src, dst, interpret=self.interpret)
+                            for p in self.pools)
         if self.verify_copies:          # differential harness: bit-exact?
-            ok = bool(jnp.array_equal(self.k_pages[:, new],
-                                      self.k_pages[:, pi])) and \
-                bool(jnp.array_equal(self.v_pages[:, new],
-                                     self.v_pages[:, pi]))
-            self.copy_checks.append(ok)
+            self.copy_checks.append(all(
+                bool(jnp.array_equal(p[:, new], p[:, pi]))
+                for p in self.pools))
         self.refs[pi] -= 1
         e.pages[idx] = new
         self.cow_splits += 1
@@ -326,41 +346,41 @@ class PagedKVRuntime:
         return deduped
 
     # ------------------------------------------------------- tier staging
-    def read_pages(self, ids: list[int]) -> tuple[np.ndarray, np.ndarray]:
-        """Pages ``ids`` as contiguous (L, pow2(n), page, KV, Dh) host
-        buffers, the padded slots repeating the last page: one gather
-        kernel per pool, at a power-of-two width so tier moves compile
-        O(log) shapes, then one D2H copy. Spans: ``kv.gather`` (the two
-        dispatches; their device work is the trace's
+    def read_pages(self, ids: list[int]) -> tuple[np.ndarray, ...]:
+        """Pages ``ids`` as contiguous (L, pow2(n), page, *row) host
+        buffers, one per pool, the padded slots repeating the last page:
+        one gather kernel per pool, at a power-of-two width so tier moves
+        compile O(log) shapes, then one D2H copy each. Spans:
+        ``kv.gather`` (the dispatches; their device work is the trace's
         ``jit_gather_pages``), ``kv.d2h`` (the blocking copies)."""
         (pad,) = _pad_ids(list(ids), _pow2(len(ids)))
         with span("kv.gather"):
             pad = jnp.asarray(pad)
-            k = gather_pages(self.k_pages, pad, interpret=self.interpret)
-            v = gather_pages(self.v_pages, pad, interpret=self.interpret)
+            bufs = [gather_pages(p, pad, interpret=self.interpret)
+                    for p in self.pools]
         with span("kv.d2h"):
-            return np.asarray(k), np.asarray(v)
+            return tuple(np.asarray(b) for b in bufs)
 
-    def stage_out(self, program_id: str
-                  ) -> tuple[np.ndarray, np.ndarray, int]:
+    def stage_out(self, program_id: str) -> tuple:
         """The program's scattered pages as contiguous host staging
-        buffers (:meth:`read_pages`, padding included, so
+        buffers, one per pool (:meth:`read_pages`, padding included, so
         :meth:`restore` copies them back as they are) — the unit a tier
-        move DMAs to host DRAM in one transfer — and its length."""
+        move DMAs to host DRAM in one transfer — then its length."""
         e = self.programs[program_id]
         return (*self.read_pages(e.pages), e.length)
 
-    def restore(self, program_id: str, k_staging, v_staging,
-                length: int) -> list[int]:
-        """Scatter staged buffers (:meth:`stage_out`'s, W pages wide, W a
-        power of two) back into freshly allocated physical pages: the
-        leading ``ceil(length / page)`` = n pages, fewer than were staged
-        where the usable prefix shrank. Each buffer goes to the device as
-        it is, with no host copy (``kv.h2d``, once per pool: the copy's
-        dispatch, as the transfer completes asynchronously); one dispatch
-        for both pools (``kv.scatter``, the trace's ``jit__restore_pages``)
-        pads on the device, slot i carrying staged page min(i, n - 1)."""
-        W = k_staging.shape[1]
+    def restore(self, program_id: str, *staged_and_length) -> list[int]:
+        """Scatter staged buffers (:meth:`stage_out`'s, one per pool, W
+        pages wide, W a power of two; the length last) back into freshly
+        allocated physical pages: the leading ``ceil(length / page)`` = n
+        pages, fewer than were staged where the usable prefix shrank.
+        Each buffer goes to the device as it is, with no host copy
+        (``kv.h2d``, once per pool: the copy's dispatch, as the transfer
+        completes asynchronously); one dispatch for every pool
+        (``kv.scatter``, the trace's ``jit__restore_pages``) pads on the
+        device, slot i carrying staged page min(i, n - 1)."""
+        *staged, length = staged_and_length
+        W = staged[0].shape[1]
         n = math.ceil(length / self.page_size)
         assert W == _pow2(W) and 0 < n <= W, (W, n)
         stale = self.programs.pop(program_id, None)
@@ -376,14 +396,14 @@ class PagedKVRuntime:
                 self._deref(pi)
             raise
         (ids,) = _pad_ids(pages, W)
-        with span("kv.h2d"):
-            k = jax.device_put(k_staging)
-        with span("kv.h2d"):
-            v = jax.device_put(v_staging)
+        on_device = []
+        for buf in staged:
+            with span("kv.h2d"):
+                on_device.append(jax.device_put(buf))
         with span("kv.scatter"):
-            self.k_pages, self.v_pages = _restore_pages(
-                self.k_pages, self.v_pages, k, v, jnp.asarray(ids),
-                jnp.asarray(n, jnp.int32), interpret=self.interpret)
+            self._set_pools(_restore_pages(
+                self.pools, tuple(on_device), jnp.asarray(ids),
+                jnp.asarray(n, jnp.int32), interpret=self.interpret))
         self.programs[program_id] = ProgramEntry(pages, length)
         return pages
 
@@ -442,16 +462,17 @@ class PagedKVRuntime:
             return logits[0, 0]
 
     def _scatter_from(self, cache, e: ProgramEntry, start: int, count: int):
-        """Copy cache[k/v][:, 0, start:start+count] into physical pages."""
+        """Copy each cache leaf's [:, 0, start:start+count] into its
+        pool's physical pages."""
         ps = self.page_size
         blocks = list(range(start // ps, (start + count - 1) // ps + 1))
         ids = [self._writable_page(e, b) for b in blocks]  # COW-split shared
         ids, blocks = _pad_ids(ids, _pow2(len(ids)), blocks)
-        self.k_pages, self.v_pages = _scatter_span(
-            self.k_pages, self.v_pages, cache["k"], cache["v"],
+        self._set_pools(_scatter_span(
+            self.pools, tuple(cache[k] for k in self.cache_keys),
             jnp.asarray(ids), jnp.asarray(blocks),
             jnp.asarray(start, jnp.int32),
-            jnp.asarray(start + count, jnp.int32), interpret=self.interpret)
+            jnp.asarray(start + count, jnp.int32), interpret=self.interpret))
 
     def _gather_into(self, cache, e: ProgramEntry):
         """Pages covering [0, e.length) -> the scratch's leading positions.
@@ -459,81 +480,138 @@ class PagedKVRuntime:
         forward overwrites the chunk's own span and masks the rest."""
         ps = self.page_size
         n = math.ceil(e.length / ps)
-        T = cache["k"].shape[2]
+        keys = self.cache_keys
+        T = cache[keys[0]].shape[2]
         (ids,) = _pad_ids(e.pages[:n], min(_pow2(n), T // ps))
-        cache["k"], cache["v"] = _gather_span(
-            cache["k"], cache["v"], self.k_pages, self.v_pages,
-            jnp.asarray(ids), interpret=self.interpret)
+        cache.update(zip(keys, _gather_span(
+            tuple(cache[k] for k in keys), self.pools, jnp.asarray(ids),
+            interpret=self.interpret)))
         return cache
 
     # ------------------------------------------------------------ decode
-    def _decode_step_impl(self, params, k_pages, v_pages, toks, tables,
-                          lens, app_pages, app_offs):
+    def _decode_step_impl(self, params, pools, toks, tables, lens,
+                          app_pages, app_offs):
         """One fused decode step for a whole batch: toks (B,) last tokens;
         tables (B, n_tab) sentinel-0-padded ragged block tables; lens (B,)
         CURRENT lengths (the kernel attends over the old pages; the new
-        token's own k/v is merged analytically); app_pages/app_offs (B,)
-        where each sequence's new k/v lands. One ``lax.scan`` over layers,
-        one ``paged_decode_attention`` per layer for ALL B programs, and
-        ONE ``append_tokens`` scatter for all B x L new k/v rows — the
-        pools are consumed in their native layout (no per-layer slice, no
-        transpose, no dtype-cast copy of the pool, ROADMAP 4(a))."""
+        token's own row is merged analytically); app_pages/app_offs (B,)
+        where each sequence's new row lands. The leading dense layers,
+        then one ``lax.scan`` over the stacked blocks (pool layer i is
+        model layer i); one paged attention kernel per layer for ALL B
+        programs, and ONE ``append_tokens`` scatter for all B x L new rows
+        — the pools are consumed in their native layout (no per-layer
+        slice, no transpose, no dtype-cast copy of the pool, ROADMAP
+        4(a)). Returns the logits, the next tokens, the pools and the
+        step's expert copies (``moe_copies``)."""
         cfg = self.cfg
-        B = toks.shape[0]
-        KV, Dh, H = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
-        G = H // KV
-        scale = 1.0 / math.sqrt(Dh)
-        L = cfg.num_layers
         cparams = cast_params(params, self.model.specs(), cfg.compute_dtype)
         x = cparams["embed"][toks][:, None].astype(cfg.compute_dtype)
         if cfg.scale_embeddings:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-        positions = lens[:, None]          # (B, 1): new token at `length`
+        attend = self._mla_attend if cfg.mla else self._gqa_attend
 
-        def body(x, inp):
-            li, p = inp
+        def layer(x, li, p):
             h = rms_norm(x, p["ln1"], cfg.norm_eps)
-            q, k, v = attn_mod.qkv_project(p["attn"], h, cfg, positions)
-            qd = q[:, 0]                               # (B, H, Dh)
-            k_new, v_new = k[:, 0], v[:, 0]            # (B, KV, Dh)
-            acc, m, l = paged_decode_attention(
-                qd, k_pages, v_pages, tables, lens, layer=li, scale=scale,
-                interpret=self.interpret, return_residuals=True)
-            # merge the new token's own (k, v) — not yet in any page —
-            # into the kernel's online-softmax state, exactly
-            qg = qd.reshape(B, KV, G, Dh).astype(jnp.float32)
-            kf = k_new.astype(jnp.float32)
-            vf = v_new.astype(jnp.float32)
-            s_self = jnp.einsum("bkgd,bkd->bkg", qg, kf) * scale
-            m2 = jnp.maximum(m, s_self)
-            alpha = jnp.exp(m - m2)
-            p_self = jnp.exp(s_self - m2)
-            acc2 = acc * alpha[..., None] \
-                + p_self[..., None] * vf[:, :, None, :]
-            l2 = l * alpha + p_self
-            o = (acc2 / jnp.maximum(l2, 1e-30)[..., None]).reshape(B, H, Dh)
-            a = attn_mod.out_project(p["attn"], o.astype(x.dtype)[:, None])
+            a, rows = attend(p["attn"], h, pools, tables, lens, li)
             x = x + a
             h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+            held = jnp.zeros((), jnp.int32)
             if "router" in p["mlp"]:
-                from repro.models.moe import moe_apply
-                x = x + moe_apply(p["mlp"], h2, cfg)
+                f, held = moe_dropless(p["mlp"], h2, cfg)
             else:
-                x = x + mlp_apply(p["mlp"], h2, cfg.activation)
-            return x, (k_new, v_new)
+                f = mlp_apply(p["mlp"], h2, cfg.activation)
+            return x + f, (rows, held)
 
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (jnp.arange(L, dtype=jnp.int32), cparams["blocks"]))
-        # ks/vs (L, B, KV, Dh): every layer's new-token k/v, scattered
-        # into the (exclusive) append pages in ONE aliased pallas call
-        k_pages, v_pages = append_tokens(k_pages, v_pages, ks, vs,
-                                         app_pages, app_offs,
-                                         interpret=self.interpret)
+        first_k = cfg.moe.first_k_dense if cfg.moe else 0
+        dense = []
+        for i in range(first_k):             # leading dense layers
+            x, out = layer(x, jnp.asarray(i, jnp.int32),
+                           take_layer(cparams["dense_layers"], i))
+            dense.append(out)
+        x, (rows, held) = jax.lax.scan(
+            lambda x, inp: layer(x, *inp), x,
+            (jnp.arange(first_k, cfg.num_layers, dtype=jnp.int32),
+             cparams["blocks"]))
+        if dense:                            # their rows lead the scan's
+            rows = jax.tree.map(
+                lambda r, *d: jnp.concatenate([jnp.stack(d), r]), rows,
+                *[out[0] for out in dense])
+        # rows: every layer's new-token row per pool (L, B, *row),
+        # scattered into the (exclusive) append pages in ONE aliased call
+        pools = append_tokens(pools, rows, app_pages, app_offs,
+                              interpret=self.interpret)
         x = rms_norm(x, cparams["final_norm"], cfg.norm_eps)
         head = cparams["embed"].T if cfg.tie_embeddings else cparams["lm_head"]
         logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))[:, 0]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return logits, nxt, k_pages, v_pages
+        copies = None
+        if cfg.moe is not None:          # [to a held expert, to any expert]
+            n_all = (cfg.num_layers - first_k) * cfg.moe.top_k * toks.shape[0]
+            copies = jnp.stack([jnp.sum(held), jnp.int32(n_all)])
+        return logits, nxt, pools, copies
+
+    def _gqa_attend(self, p, h, pools, tables, lens, li):
+        """Grouped-query attention of one layer over the K/V pools: the
+        kernel's online-softmax state, with the new token's own (k, v) —
+        not yet in any page — merged in exactly. Returns the attention
+        output and the new token's (k, v) rows."""
+        cfg = self.cfg
+        B = h.shape[0]
+        KV, Dh, H = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
+        G = H // KV
+        scale = 1.0 / math.sqrt(Dh)
+        k_pages, v_pages = pools
+        q, k, v = attn_mod.qkv_project(p, h, cfg, lens[:, None])
+        qd = q[:, 0]                               # (B, H, Dh)
+        k_new, v_new = k[:, 0], v[:, 0]            # (B, KV, Dh)
+        acc, m, l = paged_decode_attention(
+            qd, k_pages, v_pages, tables, lens, layer=li, scale=scale,
+            interpret=self.interpret, return_residuals=True)
+        qg = qd.reshape(B, KV, G, Dh).astype(jnp.float32)
+        kf = k_new.astype(jnp.float32)
+        vf = v_new.astype(jnp.float32)
+        s_self = jnp.einsum("bkgd,bkd->bkg", qg, kf) * scale
+        m2 = jnp.maximum(m, s_self)
+        alpha = jnp.exp(m - m2)
+        p_self = jnp.exp(s_self - m2)
+        acc2 = acc * alpha[..., None] + p_self[..., None] * vf[:, :, None, :]
+        l2 = l * alpha + p_self
+        o = (acc2 / jnp.maximum(l2, 1e-30)[..., None]).reshape(B, H, Dh)
+        return attn_mod.out_project(p, o.astype(h.dtype)[:, None]), \
+            (k_new, v_new)
+
+    def _mla_attend(self, p, h, pools, tables, lens, li):
+        """Absorbed latent attention of one layer over the latent pool:
+        each head's query is taken into the latent space (q_nope W_kb^T,
+        then its rotary part, zero-padded to the row's stored width),
+        attends over the rows [c, k_pe] of the
+        pages and over the new token's own row, exactly merged; the
+        latent output (attention over c) leaves through W_vb and W_o.
+        Returns the attention output and the new token's row."""
+        cfg = self.cfg
+        a = cfg.mla
+        R, Dn = a.kv_lora_rank, a.qk_nope_head_dim
+        scale = 1.0 / math.sqrt(a.qk_head_dim)
+        (c_pages,) = pools
+        pos = lens[:, None]
+        q = attn_mod.mla_query(p, h, cfg, pos)[:, 0]         # (B, H, Dn+Dr)
+        row = attn_mod.mla_latent(p, h, cfg, pos)[:, 0]      # (B, W)
+        q_lat = jnp.einsum("bhk,rhk->bhr", q[..., :Dn], p["wkb"])
+        pad = jnp.zeros(q.shape[:2] + (a.cache_width - a.cache_dim,), q.dtype)
+        qf = jnp.concatenate([q_lat, q[..., Dn:], pad], axis=-1)  # (B, H, W)
+        acc, m, l = paged_mla_decode(qf, c_pages, tables, lens, layer=li,
+                                     scale=scale, value_dim=R,
+                                     interpret=self.interpret)
+        rf = row.astype(jnp.float32)
+        s_self = jnp.einsum("bhd,bd->bh", qf.astype(jnp.float32), rf) * scale
+        m2 = jnp.maximum(m, s_self)
+        alpha = jnp.exp(m - m2)
+        p_self = jnp.exp(s_self - m2)
+        acc2 = acc * alpha[..., None] + p_self[..., None] * rf[:, None, :R]
+        l2 = l * alpha + p_self
+        o_lat = acc2 / jnp.maximum(l2, 1e-30)[..., None]     # (B, H, R)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(h.dtype), p["wvb"])
+        return attn_mod.out_project(p, o[:, None]), (row,)
 
     def decode_batch(self, params, program_ids: list[str]) -> list[jax.Array]:
         """One decode step for the WHOLE batch through one fused kernel
@@ -577,10 +655,11 @@ class PagedKVRuntime:
                 "append pages must be pairwise distinct (COW resolved above)"
             toks = jnp.stack([self._last_token(params, pid)
                               for pid in program_ids])
-            logits, nxt, self.k_pages, self.v_pages = self._decode_step(
-                params, self.k_pages, self.v_pages, toks,
-                jnp.asarray(tables), jnp.asarray(lens),
-                jnp.asarray(app_pages), jnp.asarray(app_offs))
+            logits, nxt, pools, self.moe_copies = self._decode_step(
+                params, self.pools, toks, jnp.asarray(tables),
+                jnp.asarray(lens), jnp.asarray(app_pages),
+                jnp.asarray(app_offs))
+            self._set_pools(pools)
             for i, pid in enumerate(program_ids):
                 self.programs[pid].length += 1
                 self._last[pid] = nxt[i]

@@ -178,9 +178,9 @@ class TestAppendTokensRefcounts:
         v_tok = jnp.asarray(rng.normal(size=(L, B, KV, Dh)), jnp.float32)
         page_ids = jnp.asarray([3, 0, 7, 5], jnp.int32)
         offsets = jnp.asarray([0, 7, 3, 3], jnp.int32)
-        k2, v2 = append_tokens(k_pages, v_pages, k_tok, v_tok,
+        k2, v2 = append_tokens((k_pages, v_pages), (k_tok, v_tok),
                                page_ids, offsets)
-        kr, vr = append_tokens_ref(k_pages, v_pages, k_tok, v_tok,
+        kr, vr = append_tokens_ref((k_pages, v_pages), (k_tok, v_tok),
                                    page_ids, offsets)
         assert np.array_equal(np.asarray(k2), np.asarray(kr))
         assert np.array_equal(np.asarray(v2), np.asarray(vr))

@@ -60,17 +60,23 @@ def test_prefill_decode_parity(arch, capacity, rng):
     dispatch drops different tokens for different token counts, which is
     a dispatch property, not a serving-path one. ``configured`` keeps
     the arch's own capacity factor and holds it to
-    CAPACITY_DROP_REL_TOL of each position's largest |logit|."""
+    CAPACITY_DROP_REL_TOL of each position's largest |logit|.
+
+    Every forward runs jitted, as the served path does: run op by op on
+    the CPU, each bf16 matmul is its own program, whose kernel choice
+    (and so its rounding) varies with the row count, so two forwards of
+    the same token could differ in a last bit before any cache is read."""
     cfg = get_config(arch, smoke=True)
     if cfg.moe is not None and capacity == "no_drop":
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=cfg.moe.num_experts / cfg.moe.top_k))
     model = Model(cfg)
+    forward = jax.jit(model.forward, static_argnames=("mode", "logits_slice"))
     params = model.init(jax.random.fold_in(rng, 10))
     B, S, extra = 2, 25, 4                      # odd S exercises chunk padding
     tokens = jax.random.randint(jax.random.fold_in(rng, 11), (B, S + extra), 0,
                                 cfg.vocab_size)
-    full_logits, _ = model.forward(params, tokens=tokens, mode="train")
+    full_logits, _ = forward(params, tokens=tokens, mode="train")
 
     def check(got, want):
         got, want = np.asarray(got), np.asarray(want)
@@ -82,12 +88,12 @@ def test_prefill_decode_parity(arch, capacity, rng):
         assert (got.argmax(-1) == want.argmax(-1)).all()
 
     cache = model.init_cache(B, 64)
-    pre, cache = model.forward(params, tokens=tokens[:, :S], cache=cache,
-                               cache_len=0, mode="prefill", logits_slice=1)
+    pre, cache = forward(params, tokens=tokens[:, :S], cache=cache,
+                         cache_len=0, mode="prefill", logits_slice=1)
     check(pre[:, 0], full_logits[:, S - 1])
     cl = S
     for i in range(extra):
-        step_logits, cache = model.forward(
+        step_logits, cache = forward(
             params, tokens=tokens[:, S + i:S + i + 1], cache=cache,
             cache_len=jnp.full((B,), cl, jnp.int32), mode="decode",
             logits_slice=1)

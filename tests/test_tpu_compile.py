@@ -1,9 +1,11 @@
 """The served path's kernels and steps compile for a TPU v5e at the full
-width of qwen2-1.5b, with no chip attached: the TPU compiler runs for a
-described device and refuses what the chip would (unaligned blocks, too
-much fast memory, a program that does not fit). Every compile here keeps
-the kernels as Mosaic custom calls, and the whole decode step and the
-prefill extend fit the chip at the engine's default KV budget."""
+width of qwen2-1.5b, and of the benchmark's Moonlight cut (latent
+attention, held experts), with no chip attached: the TPU compiler runs
+for a described device and refuses what the chip would (unaligned
+blocks, too much fast memory, a program that does not fit). Every
+compile here keeps the kernels as Mosaic custom calls, and the whole
+decode step and the prefill extend fit the chip at the engine's default
+KV budget (Moonlight: at the configuration file's budget)."""
 import os
 
 import jax
@@ -12,7 +14,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.decode_attention.kernel import paged_decode_attention_kernel
+from repro.kernels.decode_attention.kernel import (
+    paged_decode_attention_kernel, paged_mla_decode_kernel)
 from repro.kernels.page_copy.kernel import (page_gather_kernel,
                                             page_scatter_kernel,
                                             token_append_kernel)
@@ -85,7 +88,7 @@ def test_page_copy_kernels(one_chip, kernel):
     else:
         tok = sds(one_chip, (L, B, KV, DH), "bfloat16")
         c = compile_for(lambda k, v, kt, vt, i, o: token_append_kernel(
-            k, v, kt, vt, i, o, interpret=False),
+            (k, v), (kt, vt), i, o, interpret=False),
             pool, pool, tok, tok, sds(one_chip, (B,), "int32"),
             sds(one_chip, (B,), "int32"))
     assert "tpu_custom_call" in c.as_text()
@@ -112,7 +115,7 @@ def served(one_chip):
 def test_decode_step_compiles_kernels_and_fits(one_chip, served):
     rt, params, pool = served
     i32 = lambda *shape: sds(one_chip, shape, "int32")
-    c = compile_for(rt._decode_step_impl, params, pool, pool, i32(B),
+    c = compile_for(rt._decode_step_impl, params, (pool, pool), i32(B),
                     i32(B, MAX_LEN // PAGE), i32(B), i32(B), i32(B))
     text = c.as_text()
     # the attention kernel (scanned over layers) and the token append,
@@ -134,9 +137,63 @@ def test_prefill_extend_fits(one_chip, served):
     n = CHUNK // PAGE + 1
     ids = sds(one_chip, (1 << (n - 1).bit_length(),), "int32")
     write = _scatter_span.lower(
-        pool, pool, cache["k"], cache["v"], ids, ids,
+        (pool, pool), (cache["k"], cache["v"]), ids, ids,
         sds(one_chip, (), "int32"), sds(one_chip, (), "int32"),
         interpret=False).compile()
     assert "tpu_custom_call" in write.as_text()
     params_bytes = CFG.param_count() * 4
     assert params_bytes + total_bytes(write) < HBM
+
+
+def moonlight_cut():
+    """The benchmark's Moonlight configuration as the program serves it,
+    and its KV budget in bytes."""
+    import dataclasses
+    import json
+    import pathlib
+    f = json.loads((pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+                    / "configs" / "moonlight-16b-a3b-ep8pp2.json")
+                   .read_text())
+    m = f["model"]
+    c = get_config(f["registry"])
+    cfg = dataclasses.replace(
+        c, num_layers=m["num_hidden_layers"], vocab_size=m["vocab_size"],
+        moe=dataclasses.replace(c.moe, held=m["n_routed_experts"]))
+    return cfg, f["serve"]["kv_budget_gb"] * 1e9
+
+
+def test_paged_mla_decode_kernel(one_chip):
+    cfg, _ = moonlight_cut()
+    W = cfg.mla.cache_width
+    c = compile_for(
+        lambda q, p, t, n, layer: paged_mla_decode_kernel(
+            q, p, t, n, layer=layer, scale=0.07,
+            value_dim=cfg.mla.kv_lora_rank, interpret=False),
+        sds(one_chip, (B, cfg.num_heads, W), "bfloat16"),
+        sds(one_chip, (cfg.num_layers, POOL, PAGE, W), "bfloat16"),
+        sds(one_chip, (B, MAX_LEN // PAGE), "int32"),
+        sds(one_chip, (B,), "int32"), sds(one_chip, (), "int32"))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_moonlight_decode_step_fits_at_the_files_budget(one_chip):
+    """24 rows (the cell's closed loop) over 4096-token tables: the
+    latent kernel and the grouped expert matmuls compile, the pool keeps
+    its row-major layout (no whole-pool relayout copy), and the step fits
+    the chip beside the float32 weights."""
+    cfg, budget = moonlight_cut()
+    rows, pages = 24, int(budget / (PAGE * cfg.kv_bytes_per_token(2))) + 48
+    rt = PagedKVRuntime(cfg, n_pages=1, page_size=PAGE, interpret=False)
+    params = jax.tree.map(lambda s: sds(one_chip, s.shape, s.dtype),
+                          rt.model.abstract())
+    pool = sds(one_chip, (cfg.num_layers, pages, PAGE, cfg.mla.cache_width),
+               "bfloat16")
+    i32 = lambda *shape: sds(one_chip, shape, "int32")
+    c = compile_for(rt._decode_step_impl, params, (pool,), i32(rows),
+                    i32(rows, 4096 // PAGE), i32(rows), i32(rows), i32(rows))
+    text = c.as_text()
+    assert text.count("tpu_custom_call") >= 3       # attention, experts, append
+    pool_copies = [ln for ln in text.splitlines() if " copy(" in ln
+                   and f",{pages},{PAGE}," in ln.split("=", 1)[1][:60]]
+    assert len(pool_copies) <= 1, pool_copies       # the undonated append
+    assert total_bytes(c) < HBM, total_bytes(c)
